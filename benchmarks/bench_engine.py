@@ -38,11 +38,7 @@ from typing import Any, Dict, List
 REPO_ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO_ROOT / "src"))
 
-import jax  # noqa: E402  (before repro so the compat shim can patch it)
-
-from repro.jax_compat import ensure_jax_compat  # noqa: E402
-
-ensure_jax_compat()
+import jax  # noqa: E402
 
 BENCH_SCHEMA = "gllm-bench-engine/2"
 

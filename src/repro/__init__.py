@@ -1,16 +1,2 @@
 """gLLM reproduction: globally-balanced pipeline-parallel LLM serving.
-
-Importing the package normalizes the JAX API surface across the versions we
-deploy on (see jax_compat.py) so the runtime, tests, and examples can use
-the modern spelling everywhere.  The shim only fires when jax is already
-loaded — jax-free paths (scheduler, simulator, benchmarks) stay jax-free;
-the jax-using modules (distributed/pipeline.py, launch/mesh.py) install it
-themselves.
 """
-
-import sys
-
-from repro.jax_compat import ensure_jax_compat
-
-if "jax" in sys.modules:
-    ensure_jax_compat()

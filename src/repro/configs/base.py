@@ -157,6 +157,26 @@ class ArchConfig:
     def with_plan(self, **kw) -> "ArchConfig":
         return dataclasses.replace(self, plan=dataclasses.replace(self.plan, **kw))
 
+    def on_stages(self, pp: int) -> "ArchConfig":
+        """The published depth split over `pp` pipeline stages (tp=1).
+
+        `pattern` counts layers *per stage*, so changing `plan.pp` alone
+        would change the model's depth.  The stage-local pattern is rebuilt
+        here as one block of ``num_layers // pp`` layers, so that
+        ``layers_per_stage * pp == num_layers``.  Raises when the layers do
+        not divide, or when the pattern mixes block kinds.
+        """
+        if len(self.pattern) != 1:
+            raise ValueError(f"{self.name}: a mixed block pattern has no "
+                             f"published per-stage split")
+        if pp < 1 or self.num_layers % pp:
+            raise ValueError(f"{self.name}: {self.num_layers} layers do not "
+                             f"split over pp={pp} stages")
+        return dataclasses.replace(
+            self,
+            pattern=(BlockSpec(self.pattern[0].kind, self.num_layers // pp),),
+            plan=ParallelPlan(pp=pp, tp=1, ep_over_data=False))
+
     def params_per_layer_estimate(self) -> Dict[str, float]:
         """Rough analytic parameter counts (used by roofline MODEL_FLOPS)."""
         d = self.d_model

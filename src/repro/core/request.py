@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import enum
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 
 class RequestState(enum.Enum):
@@ -111,6 +111,9 @@ class Request:
     sampling: SamplingParams = field(default_factory=SamplingParams)
     state: RequestState = RequestState.WAITING
     output_token_ids: List[int] = field(default_factory=list)
+    # Per output token, where the backend computes them (the engine does):
+    # the two largest log-probs of the distribution it was drawn from.
+    output_logprobs: List[Tuple[float, float]] = field(default_factory=list)
     # Chunked-prefill progress over the *effective* prompt (see below).  After a
     # preemption the generated tokens are folded into the effective prompt and
     # recomputed, so num_prefilled always counts tokens whose KV is resident.

@@ -24,13 +24,10 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.distributed.optimizer import AdamConfig, AdamState, adam_update
-from repro.jax_compat import ensure_jax_compat
 from repro.launch.mesh import manual_axes
 from repro.models import serve as serve_lib
 from repro.models import transformer as tfm
 from repro.models.serve import ServeDims
-
-ensure_jax_compat()   # this module calls jax.shard_map (modern surface)
 
 
 # ----------------------------------------------------------------------------
@@ -304,17 +301,17 @@ def build_train_step(cfg: ArchConfig, mesh: Mesh, *,
 # ----------------------------------------------------------------------------
 
 def build_serve_tick(cfg: ArchConfig, mesh: Mesh, dims: ServeDims,
-                     *, unroll: Optional[bool] = None,
-                     carry_dims: Optional[ServeDims] = None):
+                     *, carry_dims: Optional[ServeDims] = None):
     """Returns (tick_fn, specs) where
 
     tick_fn(params, caches, carry, meta, fresh) ->
-        (new_carry, new_caches, tokens, sample_hidden)
+        (new_carry, new_caches, tokens, top_logprobs)
 
     carry  = {"xp": [S, DSp, W, d], "xd": [S, DSd, 1, d]}
     fresh  = {"xp": [DSp, W, d], "xd": [DSd, 1, d]}  (stage-0 inputs, embedded)
     meta   = stage-stacked ServeMeta dict
     tokens = [D*(Sp+Sd)] int32 sampled ids (greedy), -1 for padding rows
+    top_logprobs = [D*(Sp+Sd), 2] f32: each row's two largest log-probs
 
     **Bucketed programs.**  When `carry_dims` is given (the FULL ladder dims,
     `dims` being a smaller bucket from `bucket_ladder`), the tick accepts and
@@ -326,9 +323,6 @@ def build_serve_tick(cfg: ArchConfig, mesh: Mesh, dims: ServeDims,
     and donation-compatible — across every program in the ladder; meta and
     fresh arrive already at bucket shape.
     """
-    import os
-    if unroll is None:
-        unroll = os.environ.get("REPRO_SERVE_UNROLL", "1") not in ("0", "")
     S = cfg.plan.pp
     man = manual_axes(mesh)
     perm = [(i, (i + 1) % S) for i in range(S)]
@@ -354,7 +348,7 @@ def build_serve_tick(cfg: ArchConfig, mesh: Mesh, dims: ServeDims,
             xd = jnp.where(stage == 0, fresh_xd, xd)
 
         xp2, xd2, new_caches = serve_lib.stage_forward_serve(
-            cfg, stage_params, caches, xp, xd, meta, dims, unroll=unroll)
+            cfg, stage_params, caches, xp, xd, meta, dims)
 
         # rows whose logits sample a token (outside, on the last stage's out)
         samples = []
@@ -419,8 +413,8 @@ def build_serve_tick(cfg: ArchConfig, mesh: Mesh, dims: ServeDims,
         else:
             tokens = greedy
         logprobs = jax.nn.log_softmax(logits, axis=-1)
-        top = jnp.max(logprobs, axis=-1)
-        return ({"xp": xp_n, "xd": xd_n}, caches_n, tokens, top)
+        top2 = jax.lax.top_k(logprobs, 2)[0]
+        return ({"xp": xp_n, "xd": xd_n}, caches_n, tokens, top2)
 
     specs = {
         "params_stages": (w_full, w_man),

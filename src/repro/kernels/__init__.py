@@ -1,8 +1,8 @@
 """Pallas TPU kernels for the serving hot spots (DESIGN.md §6).
 
-Each kernel: pl.pallas_call + explicit BlockSpec VMEM tiling; ops.py is
-the dispatch layer (TPU kernel / CPU interpret / jnp oracle) and ref.py
-holds the pure-jnp oracles the tests sweep against."""
+Each kernel: pl.pallas_call + explicit BlockSpec VMEM tiling; ops.py holds
+the dispatch rule (the kernel on a TPU, the jnp path elsewhere) and ref.py
+the pure-jnp oracles the tests sweep against."""
 
 from repro.kernels.mamba_scan import mamba_chunked_scan
 from repro.kernels.moe_gemm import fused_moe_ffn

@@ -1,48 +1,13 @@
-"""Dispatch layer: Pallas kernels on TPU, interpret-mode on CPU, jnp oracle
-as the portable fallback.
-
-The model code (`repro.models.attention` / `repro.models.ssm`) uses the pure
-jnp path by default — identical math, XLA-fused — and flips to these kernels
-on real TPU via `use_kernels()`.  The dry-run always lowers the jnp path
-(Pallas TPU kernels cannot lower for the CPU backend); kernels are validated
-in interpret mode by the test suite.
+"""Dispatch rule for the Pallas kernels: on a TPU the model code always
+takes the kernel; elsewhere it runs the jnp path (identical math,
+XLA-fused).  Interpret mode runs only where a caller asks for it — the
+kernel tests pass ``interpret=True`` to the kernels directly.
 """
 
 from __future__ import annotations
 
-import os
-
 import jax
-
-from repro.kernels.paged_attention import paged_flash_attention
-from repro.kernels.rwkv6_scan import rwkv6_chunked_scan
-from repro.kernels import ref
 
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
-
-
-def use_kernels() -> bool:
-    env = os.environ.get("REPRO_USE_PALLAS")
-    if env is not None:
-        return env not in ("0", "false", "")
-    return on_tpu()
-
-
-def paged_attention(q, kv_pages, block_tables, context_lens, q_positions,
-                    *, interpret: bool = False):
-    """Decode/prefill paged attention: kernel on TPU, oracle elsewhere."""
-    if use_kernels() or interpret:
-        return paged_flash_attention(
-            q, kv_pages, block_tables, context_lens, q_positions,
-            interpret=interpret or not on_tpu())
-    return ref.paged_flash_attention_ref(
-        q, kv_pages, block_tables, context_lens, q_positions)
-
-
-def rwkv6_scan(r, k, v, w, u, *, chunk: int = 64, interpret: bool = False):
-    if use_kernels() or interpret:
-        return rwkv6_chunked_scan(r, k, v, w, u, chunk=chunk,
-                                  interpret=interpret or not on_tpu())
-    return ref.rwkv6_scan_ref(r, k, v, w, u)

@@ -10,7 +10,11 @@ grid walks (seq, q-block, page) and the KV BlockSpec *fetches page
 `tables[s, b]` from HBM into VMEM* while the previous page is being
 consumed (hardware double-buffering replaces the GPU's manual smem staging).
 Online softmax state lives in VMEM scratch across the minor (page) grid dim.
-All tiles are (8,128)-aligned: D = head_dim = 128/96/64, page >= 8.
+Tiles meet the TPU block rule (the last two block dims divisible by (8, 128)
+or equal to the array's) by taking whole (KH|H, D) trailing dims and
+q-blocks of 128 rows (or all of TQ); they are not (8,128)-aligned in
+general — with D = 64 the last dim is half a lane.  The query positions
+travel as [S, 1, TQ] so their block's trailing dims are (1, tq).
 """
 
 from __future__ import annotations
@@ -33,7 +37,7 @@ def _kernel(
     live_ref,              # [S] int32 live pages per sequence
     # inputs
     q_ref,                 # [1, TQ, H, D]
-    qpos_ref,              # [1, TQ] int32 global positions
+    qpos_ref,              # [1, 1, TQ] int32 global positions
     kv_ref,                # [1, page, 2, KH, D] — page tables[s, b]
     # outputs
     o_ref,                 # [1, TQ, H, D]
@@ -71,7 +75,7 @@ def _kernel(
 
         kpos = b * page + jax.lax.broadcasted_iota(jnp.int32, (page,), 0)
         ctx = ctx_ref[s]
-        qpos = qpos_ref[0]                              # [TQ]
+        qpos = qpos_ref[0, 0]                           # [TQ]
         mask = (kpos[None, :] < ctx) & (kpos[None, :] <= qpos[:, None])
 
         scale = D ** -0.5
@@ -137,7 +141,7 @@ def paged_flash_attention(
         return (s, qb, 0, 0)
 
     def pos_index(s, qb, b, tables, ctx, live):
-        return (s, qb)
+        return (s, 0, qb)
 
     def kv_index(s, qb, b, tables, ctx, live):
         bb = jnp.minimum(b, jnp.maximum(live[s] - 1, 0))
@@ -151,7 +155,7 @@ def paged_flash_attention(
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, tq, H, D), q_index),
-                pl.BlockSpec((1, tq), pos_index),
+                pl.BlockSpec((1, 1, tq), pos_index),
                 pl.BlockSpec((1, page, 2, KH, D), kv_index),
             ],
             out_specs=pl.BlockSpec((1, tq, H, D), q_index),
@@ -163,6 +167,6 @@ def paged_flash_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((S, TQ, H, D), q.dtype),
         interpret=interpret,
-    )(block_tables.reshape(-1), context_lens, live_pages, q, q_positions,
-      kv_pages)
+    )(block_tables.reshape(-1), context_lens, live_pages, q,
+      q_positions.reshape(S, 1, TQ), kv_pages)
     return out

@@ -11,10 +11,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from repro.jax_compat import ensure_jax_compat
-
-ensure_jax_compat()   # uses jax.make_mesh(axis_types=) / AxisType
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -42,6 +38,10 @@ def derive_pipeline_mesh(prod_mesh: Mesh, pp: int, tp: int) -> Mesh:
 
 
 def manual_axes(mesh: Mesh) -> frozenset:
-    """The mesh axes handled manually inside shard_map (everything except
-    `tensor`, which GSPMD auto-shards from argument shardings)."""
-    return frozenset(n for n in mesh.axis_names if n != "tensor")
+    """The mesh axes handled manually inside shard_map: everything except a
+    `tensor` axis of size > 1, which GSPMD auto-shards from argument
+    shardings.  A size-1 `tensor` axis partitions nothing and stays manual,
+    so the region is fully manual and Mosaic kernels (which cannot be
+    auto-partitioned) can run inside it."""
+    return frozenset(n for n in mesh.axis_names
+                     if n != "tensor" or mesh.shape[n] == 1)

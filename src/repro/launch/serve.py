@@ -1,9 +1,9 @@
 """Serving launcher: a thin flag->`ServeSpec` translation over the public
 serving API (`repro.serving`, DESIGN.md §10).
 
-On this CPU container, --reduced (default) builds the same-family reduced
-config so the engine actually executes; on a real TPU slice, --full uses the
-published config on the production mesh factoring from the arch's plan.
+By default the engine runs the same-family reduced config, which executes
+on a CPU; --full is the chip mode: the published config at full depth in
+bf16 on one TPU chip, its KV pool sized from the chip's memory.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
         --requests 12 --rate 4 [--policy gllm|sarathi|no_wt|no_ut] \
@@ -75,7 +75,8 @@ def main() -> None:
                     help="with --rebalance-interval: also live-migrate "
                     "running decode requests (KV moves, no recompute)")
     ap.add_argument("--full", action="store_true",
-                    help="published config on the production mesh (TPU)")
+                    help="chip mode: the published config at full depth in "
+                    "bf16 on one TPU chip")
     ap.add_argument("--spec", default=None, metavar="FILE",
                     help="serve from a ServeSpec JSON file instead of the "
                     "engine/cluster flags above")

@@ -4,8 +4,7 @@ checkpointing and elastic restart.
     PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
         --steps 50 [--ckpt /tmp/ck --resume] [--grad-compression ring8]
 
-Reduced configs on CPU (default); on a TPU slice, --full runs the published
-config on the production-mesh factoring.
+Reduced configs, on the CPU.
 """
 
 from __future__ import annotations
@@ -27,7 +26,6 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--grad-compression", default=None,
                     choices=[None, "int8", "ring8"])
-    ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
 
     import jax
@@ -39,19 +37,14 @@ def main() -> None:
     from repro.data.tokens import batches
     from repro.distributed.optimizer import AdamConfig, adam_init
     from repro.distributed.pipeline import build_train_step
-    from repro.launch.mesh import derive_pipeline_mesh, make_production_mesh
     from repro.models import transformer as tfm
     from repro.runtime.checkpoint import AsyncCheckpointer, restore_checkpoint
 
-    cfg = get_config(args.arch)
-    if args.full:
-        mesh = derive_pipeline_mesh(make_production_mesh(), cfg.plan.pp,
-                                    cfg.plan.tp)
-    else:
-        cfg = make_reduced(cfg).with_plan(pp=1, tp=1, ep_over_data=False)
-        cfg = dataclasses.replace(cfg, dtype="float32")
-        mesh = jax.make_mesh((1, 1, 1), ("data", "stage", "tensor"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
+    cfg = make_reduced(get_config(args.arch)).with_plan(
+        pp=1, tp=1, ep_over_data=False)
+    cfg = dataclasses.replace(cfg, dtype="float32")
+    mesh = jax.make_mesh((1, 1, 1), ("data", "stage", "tensor"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
     M, mbg, T = 2, mesh.shape["data"], args.seq
     ew = T // 2 if cfg.is_encoder_decoder else 0

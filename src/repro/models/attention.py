@@ -198,13 +198,14 @@ def paged_attention(
     n_blocks = Bmax // pages_per_block
     Bk = pages_per_block * page
 
-    # On real TPU, dispatch to the Pallas kernel (identical math, explicit
-    # VMEM tiling); the jnp path below is the CPU/dry-run implementation.
+    # On a TPU the Pallas kernel runs (identical math, explicit VMEM
+    # tiling); the jnp path below is the CPU implementation.
     if merge_axis is None:
         from repro.kernels import ops as kops
-        if kops.on_tpu() and kops.use_kernels():
-            return kops.paged_attention(q, cache, block_tables, context_lens,
-                                        q_positions)
+        if kops.on_tpu():
+            from repro.kernels.paged_attention import paged_flash_attention
+            return paged_flash_attention(q, cache, block_tables,
+                                         context_lens, q_positions)
 
     def kv_blk(i):
         tabs = jax.lax.dynamic_slice_in_dim(block_tables, i * pages_per_block,

@@ -86,7 +86,7 @@ def expert_ffn(xb: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     elsewhere the batched einsum is the XLA-fused grouped GEMM (GSPMD shards
     `ff` over `tensor`)."""
     from repro.kernels import ops as kops
-    if kops.on_tpu() and kops.use_kernels() and xb.shape[1] % 8 == 0 \
+    if kops.on_tpu() and xb.shape[1] % 8 == 0 \
             and w_gate.shape[-1] % 128 == 0:
         from repro.kernels.moe_gemm import fused_moe_ffn
         return fused_moe_ffn(xb, w_gate, w_up, w_down)

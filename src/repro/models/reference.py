@@ -9,7 +9,7 @@ tests and the Table-1-style output-quality benchmark.
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -65,3 +65,37 @@ def greedy_generate(
         out.append(nxt)
         toks.append(nxt)
     return out
+
+
+def greedy_generate_logits(
+    cfg: ArchConfig,
+    params,
+    prompt: Sequence[int],
+    max_new_tokens: int,
+    *,
+    width: int,
+) -> Tuple[List[int], np.ndarray]:
+    """`greedy_generate` that also returns each step's next-token logits
+    [max_new_tokens, V] (float32), for causal decoder-only models.
+
+    The forward is jitted once at a fixed `width` >= len(prompt) +
+    max_new_tokens: the tokens sit at the front of a zero-padded row, and
+    under causal attention the padding after them changes no logit at
+    their positions, so every step reuses one compiled program."""
+    if len(prompt) + max_new_tokens > width:
+        raise ValueError(f"width {width} < prompt {len(prompt)} + "
+                         f"{max_new_tokens} new tokens")
+    fwd = jax.jit(lambda p, t: dense_forward(cfg, p, t)[0])
+    row = np.zeros((1, width), np.int32)
+    row[0, :len(prompt)] = prompt
+    n = len(prompt)
+    out: List[int] = []
+    logits = []
+    for _ in range(max_new_tokens):
+        step = np.asarray(fwd(params, jnp.asarray(row))[n - 1], np.float32)
+        nxt = int(np.argmax(step))
+        logits.append(step)
+        out.append(nxt)
+        row[0, n] = nxt
+        n += 1
+    return out, np.stack(logits)

@@ -621,14 +621,9 @@ def block_apply_serve(cfg: ArchConfig, kind: BlockKind, p, xp, xd, cache,
 
 
 def stage_forward_serve(cfg: ArchConfig, stage_params, caches, xp, xd, meta,
-                        dims: ServeDims, *, unroll: bool = False):
+                        dims: ServeDims):
     """Apply one stage's blocks to its resident micro-batch (inside the
-    manual {'stage','data'} shard_map).  Returns (xp, xd, new_caches).
-
-    `unroll=True` replaces the per-block lax.scan with a Python loop whose
-    cache updates are in-place dynamic-update-slices on the donated cache
-    buffer — the scan version forces XLA to double-buffer the whole KV pool
-    every tick (§Perf iteration 1)."""
+    manual {'stage','data'} shard_map).  Returns (xp, xd, new_caches)."""
     stage_idx = jax.lax.axis_index("stage")
     layer_offset = 0
     new_caches = dict(caches) if caches else {}
@@ -664,33 +659,30 @@ def stage_forward_serve(cfg: ArchConfig, stage_params, caches, xp, xd, meta,
             (xp, xd, enc_cache), nc = apply_one((xp, xd, enc_cache), p1, c1, 0)
             if cache_i is not None and nc is not None:
                 new_caches[key] = jax.tree.map(lambda a: a[None], nc)
-        elif unroll:
-            # in-place layer loop: each layer's cache slice is updated with a
-            # dynamic-update-slice on the (donated) stacked buffer
-            acc = cache_i
-            for r in range(bs.repeat):
-                pr = jax.tree.map(lambda a: a[r], p)
-                cr = jax.tree.map(lambda a: a[r], acc) if acc else None
-                (xp, xd, enc_cache), nc = apply_one((xp, xd, enc_cache),
-                                                    pr, cr, r)
-                if acc is not None and nc is not None:
-                    acc = jax.tree.map(
-                        lambda full, upd, rr=r:
-                        jax.lax.dynamic_update_index_in_dim(full, upd, rr, 0),
-                        acc, nc)
-            if acc is not None:
-                new_caches[key] = acc
         else:
+            # layer loop with the stacked cache in the carry: each layer
+            # reads its slice and writes it back in place, so the pool is
+            # never double-buffered, and the layer body (with its kernel
+            # calls) is compiled once instead of once per layer
             def scan_body(carry, inp):
-                pl, cl, li = inp
-                carry, nc = apply_one(carry, pl, cl, li)
-                return carry, nc
+                act, acc = carry
+                pl, li = inp
+                cl = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, li, 0, keepdims=False), acc) if acc else None
+                act, nc = apply_one(act, pl, cl, li)
+                if acc:
+                    acc = jax.tree.map(
+                        lambda full, upd:
+                        jax.lax.dynamic_update_index_in_dim(full, upd, li, 0),
+                        acc, nc)
+                return (act, acc), None
 
-            (xp, xd, enc_cache), ncs = jax.lax.scan(
-                scan_body, (xp, xd, enc_cache),
-                (p, cache_i, jnp.arange(bs.repeat)))
-            if cache_i is not None and ncs is not None:
-                new_caches[key] = ncs
+            ((xp, xd, enc_cache), acc), _ = jax.lax.scan(
+                scan_body, ((xp, xd, enc_cache), cache_i),
+                (p, jnp.arange(bs.repeat)))
+            if acc:
+                new_caches[key] = acc
         layer_offset += bs.repeat
         # whisper: after the encoder group, snapshot enc hidden into the cache
         if cfg.is_encoder_decoder and bs.kind == BlockKind.ENC_LAYER \

@@ -31,6 +31,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.core import (
@@ -45,34 +47,6 @@ from repro.models import serve as serve_lib
 from repro.models import transformer as tfm
 from repro.models.serve import ServeDims
 from repro.runtime.core import ExecResult, ExecutionBackend, TickLoop
-
-
-def _mesh_scope(mesh):
-    """Context manager putting `mesh` in scope for a jitted tick call —
-    entering it only when it isn't already the active mesh.
-
-    The ambient mesh context is part of jit's compilation-cache key, and on
-    jax versions where `set_mesh` is the legacy stack-based `with mesh:`,
-    re-entering an already-active mesh *changes* that key (stack depth 2 vs
-    1).  Ticks dispatched from inside a caller's `with jax.set_mesh(...)`
-    block (engine construction, warm_start) must hit the same compiled
-    signatures as ticks dispatched bare (drain on a worker thread), so the
-    scope is made idempotent here.
-    """
-    import contextlib
-    try:
-        from jax._src.mesh import get_concrete_mesh
-        if get_concrete_mesh() == mesh:       # new-style set_mesh active
-            return contextlib.nullcontext()
-    except Exception:
-        pass
-    try:
-        from jax._src.mesh import thread_resources
-        if thread_resources.env.physical_mesh == mesh:   # legacy `with mesh:`
-            return contextlib.nullcontext()
-    except Exception:
-        pass
-    return jax.set_mesh(mesh)
 
 
 class SlotAllocator:
@@ -154,18 +128,34 @@ class JaxBackend(ExecutionBackend):
 
         self._embed = jax.jit(
             lambda p, t: jnp.take(p["embed"]["tok"], t, axis=0))
-        S = cfg.plan.pp
-        with jax.set_mesh(mesh):
-            self.caches = serve_lib.init_caches(cfg, dims, self.dtype)
-            W = dims.prefill_width
-            self.carry = {
-                "xp": jnp.zeros((S, dims.Sp, W, cfg.d_model), self.dtype),
-                "xd": jnp.zeros((S, dims.Sd, 1, cfg.d_model), self.dtype),
-            }
+        # Every tick input is placed with an explicit sharding on the
+        # engine's mesh, so its type is the same whichever mesh context the
+        # caller is in (inside a `jax.set_mesh` block or outside any) and
+        # the jit cache sees one signature per bucket.
+        self._meta_sh = self._sharding(P("stage", "data"))
+        self._fresh_sh = self._sharding(P("data", None, None))
+        self._repl_sh = self._sharding(P())
+        carry_sh = self._sharding(P("stage", "data", None, None))
+        S, W = cfg.plan.pp, dims.prefill_width
+        self.caches = jax.tree.map(
+            lambda a, spec: jnp.zeros(a.shape, a.dtype,
+                                      device=self._sharding(spec)),
+            serve_lib.abstract_caches(cfg, dims, self.dtype),
+            serve_lib.cache_pspecs(cfg, dims),
+            is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+        self.carry = {
+            "xp": jnp.zeros((S, dims.Sp, W, cfg.d_model), self.dtype,
+                            device=carry_sh),
+            "xd": jnp.zeros((S, dims.Sd, 1, cfg.d_model), self.dtype,
+                            device=carry_sh),
+        }
         self._seed = 0
         self._prep_s = 0.0          # host prepare() time since last execute
         self._zero_meta_np()        # build the template now: one-time jnp
         #                             dispatch must not bill the first tick
+
+    def _sharding(self, spec: P) -> NamedSharding:
+        return NamedSharding(self.mesh, spec)
 
     # ------------------------------------------------------- bucket programs
     def _get_tick(self, bucket: ServeDims):
@@ -199,26 +189,37 @@ class JaxBackend(ExecutionBackend):
         warm_start no serving tick compiles (``compile_count()`` is flat).
         """
         def bubble(bucket: ServeDims) -> None:
-            meta_dev = self._stack_meta(zero_ring, bucket)
-            fresh = self._build_fresh(None, bucket)
-            sampling = {
-                "temps": jnp.zeros(bucket.Sp + bucket.Sd, jnp.float32),
-                "seed": jnp.asarray(0, jnp.uint32),
-            }
             # same mesh context as execute(): the jit cache keys on the
             # ambient mesh, so warming under a different context would
             # compile signatures serving never hits
-            with _mesh_scope(self.mesh):
+            with jax.set_mesh(self.mesh):
                 self.carry, self.caches, tokens, _ = self._get_tick(bucket)(
-                    self.params, self.caches, self.carry, meta_dev, fresh,
-                    sampling)
+                    self.params, self.caches, self.carry,
+                    *self._bubble_inputs(bucket))
             np.asarray(tokens)      # block: compile + execute now, not later
 
-        zero_ring = tuple(
-            (None, self._zero_meta_np()) for _ in range(self.depth))
         for bucket in self.ladder:
             bubble(bucket)
         bubble(self.ladder[0])
+
+    def _bubble_inputs(self, bucket: ServeDims) -> tuple:
+        """(meta, fresh, sampling) of a bubble tick: zero metadata, a
+        state no-op like any pipeline bubble."""
+        ring = tuple((None, self._zero_meta_np()) for _ in range(self.depth))
+        return (self._stack_meta(ring, bucket),
+                self._build_fresh(None, bucket),
+                self._put_sampling(
+                    np.zeros(bucket.Sp + bucket.Sd, np.float32), 0))
+
+    def lower_tick(self, bucket: Optional[ServeDims] = None):
+        """The `jax.stages.Lowered` tick program of `bucket` (default: the
+        full dims) on the engine's current state — for inspecting what the
+        compiler makes of it (`.compile().as_text()`, memory analysis)."""
+        bucket = bucket or self.dims
+        with jax.set_mesh(self.mesh):
+            return self._get_tick(bucket).lower(
+                self.params, self.caches, self.carry,
+                *self._bubble_inputs(bucket))
 
     def _select_bucket(self, ring: Sequence[Tuple[Optional[int], Any]]
                        ) -> ServeDims:
@@ -267,7 +268,7 @@ class JaxBackend(ExecutionBackend):
             if not full:
                 stacked = np.ascontiguousarray(
                     self._slice_meta_field(k, stacked, bucket))
-            out[k] = jnp.asarray(stacked)
+            out[k] = jax.device_put(stacked, self._meta_sh)
         return out
 
     # --------------------------------------------------------------- protocol
@@ -293,7 +294,7 @@ class JaxBackend(ExecutionBackend):
                     if ring[0][0] is not None else None)
         fresh = self._build_fresh(entering, bucket)
         sampling = self._build_sampling(exiting_id, bucket)
-        with _mesh_scope(self.mesh):
+        with jax.set_mesh(self.mesh):
             self.carry, self.caches, tokens, top_lp = self._get_tick(bucket)(
                 self.params, self.caches, self.carry, meta_dev, fresh,
                 sampling)
@@ -338,8 +339,17 @@ class JaxBackend(ExecutionBackend):
             t1 = time.perf_counter()
             host = np.asarray(tokens)       # blocks until the tick finishes
             self.stats.device_s += time.perf_counter() - t1
-            toks = [int(host[i]) for i in prefill_rows]
-            toks += [int(host[d_off + j]) for j in range(n_decode)]
+            rows = prefill_rows + [d_off + j for j in range(n_decode)]
+            reqs = [exiting.prefill[i].request for i in prefill_rows]
+            reqs += [seq.request for seq in exiting.decode]
+            lps = np.asarray(top_lp)
+            for req, r in zip(reqs, rows):
+                # aligned with output_token_ids once the scheduler records
+                # this row's token (a discarded token's entry is overwritten)
+                del req.output_logprobs[req.num_output_tokens:]
+                req.output_logprobs.append((float(lps[r, 0]),
+                                            float(lps[r, 1])))
+            toks = [int(host[r]) for r in rows]
             self.stats.tokens_out += len(toks)
             return toks
 
@@ -449,8 +459,11 @@ class JaxBackend(ExecutionBackend):
             for j, seq in enumerate(batch.decode):
                 temps[dims.Sp + j] = seq.request.sampling.temperature
         self._seed = (self._seed + 1) % (2**31)
-        return {"temps": jnp.asarray(temps),
-                "seed": jnp.asarray(self._seed, jnp.uint32)}
+        return self._put_sampling(temps, self._seed)
+
+    def _put_sampling(self, temps: np.ndarray, seed: int) -> dict:
+        return {"temps": jax.device_put(temps, self._repl_sh),
+                "seed": jax.device_put(np.uint32(seed), self._repl_sh)}
 
     def _zero_meta_np(self) -> dict:
         if not hasattr(self, "_zm"):
@@ -522,9 +535,10 @@ class JaxBackend(ExecutionBackend):
         # program: run it under the same scope as the tick call so the
         # warm-time and serve-time signatures coincide
         if dims.Sp:
-            with _mesh_scope(self.mesh):
-                emb = np.asarray(self._embed(self.params,
-                                             jnp.asarray(p_tok)), np.float32)
+            with jax.set_mesh(self.mesh):
+                emb = np.asarray(self._embed(
+                    self.params, jax.device_put(p_tok, self._repl_sh)),
+                    np.float32)
             emb = emb[: dims.Sp, : max(dims.C, 1)]
             xp[:, dims.Te : dims.Te + emb.shape[1], :] = emb
             for s, seq in enumerate(prefill):
@@ -532,12 +546,13 @@ class JaxBackend(ExecutionBackend):
                 if enc is not None:
                     xp[s, : enc.shape[0], :] = enc
         if dims.Sd:
-            with _mesh_scope(self.mesh):
+            with jax.set_mesh(self.mesh):
                 xd[:, 0, :] = np.asarray(
-                    self._embed(self.params, jnp.asarray(d_tok)),
+                    self._embed(self.params,
+                                jax.device_put(d_tok, self._repl_sh)),
                     np.float32)[: dims.Sd, 0, :]
-        return {"xp": jnp.asarray(xp, self.dtype),
-                "xd": jnp.asarray(xd, self.dtype)}
+        return {"xp": jax.device_put(xp.astype(self.dtype), self._fresh_sh),
+                "xd": jax.device_put(xd.astype(self.dtype), self._fresh_sh)}
 
 
 class PipelineEngine:

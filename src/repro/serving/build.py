@@ -19,10 +19,16 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import os
+from pathlib import Path
 from typing import Any, List, Optional, Tuple
 
 from repro.serving.server import LLMServer
 from repro.serving.spec import ServeSpec, TraceSpec
+
+# Compile-cache directory used when JAX_COMPILATION_CACHE_DIR is unset: a
+# fixed, git-ignored path inside the checkout.
+_COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 # Reduced-mode defaults: small enough that the exact engine executes on a
 # CPU container, throttle horizons scaled to the toy bucket (the same
@@ -31,6 +37,22 @@ _REDUCED_THROTTLE = dict(num_iters_T=4, max_prefill_tokens=32,
                          min_prefill_tokens=4)
 _REDUCED_DIMS = dict(Sp=1, C=32, Sd=8, pages=512, page=8, Bp=64, Bd=64,
                      slots=16)
+
+# Chip-mode serve geometry, sized for one TPU v5e chip (16 GiB of HBM) per
+# pipeline stage: a max model length of 4096 tokens (256 pages of 16),
+# prefill chunks of C=512 tokens in Sp=2 rows, and Sd=64 decode rows.  The
+# page pool is whatever the device's memory holds after the weights and the
+# tick's temporaries (`chip_serve_dims`).
+_CHIP_DIMS = dict(Sp=2, C=512, Sd=64, page=16, Bp=256, Bd=256)
+# Device memory kept free for the tick program's temporaries other than the
+# KV copy (activations, [rows, vocab] logits, sampling) and for the
+# runtime's own buffers: about 0.9e9 bytes for Qwen1.5-0.5B on a v5e.
+_CHIP_RESERVE_BYTES = 3 << 29
+# The tick's temporaries hold a copy of the KV pool at this many times its
+# bytes: the device keeps the pool page-minor, while the paged-attention
+# kernel reads it row-major, where a head_dim of 64 fills half of each
+# 128-lane tile (measured with `compiled.memory_analysis()` for a v5e).
+_KV_COPY_FACTOR = 2
 
 
 def build(spec: ServeSpec) -> LLMServer:
@@ -65,13 +87,11 @@ def _build_engine(spec: ServeSpec) -> Tuple[Any, Any]:
     from jax.sharding import PartitionSpec as P
 
     from repro.configs import get_config, make_reduced
-    from repro.configs.base import ASSIGNED_SHAPES
-    from repro.launch.mesh import derive_pipeline_mesh, make_production_mesh
-    from repro.launch.shapes import serve_cell_dims
     from repro.models import transformer as tfm
     from repro.models.serve import ServeDims
     from repro.runtime.engine import PipelineEngine
 
+    _use_compile_cache()
     es = spec.engine
     cfg = get_config(es.arch)
     if es.reduced:
@@ -91,35 +111,107 @@ def _build_engine(spec: ServeSpec) -> Tuple[Any, Any]:
         if es.reduced_overrides:
             raise ValueError(
                 "EngineSpec.reduced_overrides only applies to reduced mode")
-        prod = make_production_mesh()
-        mesh = derive_pipeline_mesh(prod, cfg.plan.pp, cfg.plan.tp)
-        dims = serve_cell_dims(cfg, ASSIGNED_SHAPES["prefill_32k"],
-                               data=mesh.shape["data"])
-        if es.dims:
-            dims = dataclasses.replace(dims, **es.dims)
+        cfg = cfg.on_stages(es.stages)       # published widths, bf16
+        mesh = chip_mesh(es.stages)
+        dims = None                 # sized below, once the weights are placed
         th = _throttle_config(spec, cfg.plan.pp, reduced=False)
 
     n = spec.num_replicas
     record = spec.trace.record if spec.trace is not None else None
-    with jax.set_mesh(mesh):
-        params = tfm.init_params(cfg, jax.random.key(es.seed),
-                                 dtype=jnp.dtype(cfg.dtype))
-        params = jax.tree.map(
-            lambda a, s: jax.device_put(a, NamedSharding(mesh, s)),
-            params, tfm.param_pspecs(cfg),
-            is_leaf=lambda x: isinstance(x, P))
-        # replicas share the (read-only) parameter tree; each owns its KV
-        # pool, caches, scheduler, and TickLoop
-        engines = [PipelineEngine(cfg, dims, params, mesh, th,
-                                  trace_path=_replica_trace(record, i, n),
-                                  async_dispatch=es.dispatch == "async",
-                                  bucketed=es.bucketed,
-                                  enable_prefix_caching=
-                                  es.enable_prefix_caching)
-                   for i in range(n)]
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                             tfm.param_pspecs(cfg),
+                             is_leaf=lambda x: isinstance(x, P))
+    params = jax.jit(
+        lambda key: tfm.init_params(cfg, key, dtype=jnp.dtype(cfg.dtype)),
+        out_shardings=shardings)(jax.random.key(es.seed))
+    if dims is None:
+        pages = (es.dims or {}).get("pages")
+        if pages is None:
+            dims = chip_serve_dims(cfg, _free_bytes(mesh) // n)
+        else:
+            dims = ServeDims(**_CHIP_DIMS, pages=pages, slots=pages)
+        dims = dataclasses.replace(dims, **(es.dims or {}))
+    # replicas share the (read-only) parameter tree; each owns its KV
+    # pool, caches, scheduler, and TickLoop
+    engines = [PipelineEngine(cfg, dims, params, mesh, th,
+                              trace_path=_replica_trace(record, i, n),
+                              async_dispatch=es.dispatch == "async",
+                              bucketed=es.bucketed,
+                              enable_prefix_caching=es.enable_prefix_caching)
+               for i in range(n)]
     if spec.cluster is None and n == 1:
         return engines[0], cfg
     return _wrap_router(spec, engines, record), cfg
+
+
+def _use_compile_cache() -> None:
+    """Keep compiled programs across processes on an accelerator: in
+    $JAX_COMPILATION_CACHE_DIR when it is set (JAX reads it itself, and no
+    other directory is set here), else in `_COMPILE_CACHE_DIR`.  CPU runs,
+    the test suite among them, cache nothing."""
+    import jax
+    if ("JAX_COMPILATION_CACHE_DIR" in os.environ
+            or jax.default_backend() == "cpu"):
+        return
+    jax.config.update("jax_compilation_cache_dir", str(_COMPILE_CACHE_DIR))
+
+
+def chip_mesh(stages: int):
+    """Mesh (data=1, stage=`stages`, tensor=1) over the first `stages`
+    devices JAX reports."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    devices = jax.devices()
+    if len(devices) < stages:
+        raise ValueError(f"{stages} pipeline stages need {stages} devices; "
+                         f"JAX reports {len(devices)}")
+    return Mesh(np.asarray(devices[:stages]).reshape(1, stages, 1),
+                ("data", "stage", "tensor"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 3)
+
+
+def _free_bytes(mesh) -> int:
+    """Device memory not yet in use, on the fullest device of `mesh`."""
+    free = []
+    for d in mesh.devices.flat:
+        stats = d.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            raise RuntimeError(
+                f"{d} reports no memory limit: the chip build mode sizes "
+                "its KV pool from device memory and needs an accelerator "
+                "(use EngineSpec(reduced=True) on a CPU)")
+        free.append(stats["bytes_limit"] - stats["bytes_in_use"])
+    return min(free)
+
+
+def chip_serve_dims(cfg, free_bytes: int):
+    """Chip-mode `ServeDims` for `cfg` with `free_bytes` of memory left on
+    each device after the weights.
+
+    The pool's unit is one KV page plus one state slot (``slots = pages``:
+    every resident request holds at least one page, so slots never run out
+    first).  A unit costs its cache bytes on one device, plus the tick's
+    copy of them (`_KV_COPY_FACTOR`), and `_CHIP_RESERVE_BYTES` stay free for
+    the rest of the tick.
+    """
+    import jax
+
+    from repro.models import serve as serve_lib
+    from repro.models.serve import ServeDims
+
+    one = ServeDims(**_CHIP_DIMS, pages=1, slots=1)
+    unit = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(
+        serve_lib.abstract_caches(cfg, one))) // cfg.plan.pp
+    unit *= 1 + _KV_COPY_FACTOR
+    pages = (free_bytes - _CHIP_RESERVE_BYTES) // unit
+    need = max(_CHIP_DIMS["Bp"], _CHIP_DIMS["Bd"])
+    if pages < need:
+        raise ValueError(
+            f"{cfg.name}: {free_bytes} free bytes hold {pages} KV pages, "
+            f"fewer than one max-length sequence ({need})")
+    return ServeDims(**_CHIP_DIMS, pages=int(pages), slots=int(pages))
 
 
 def _replica_trace(record: Optional[str], i: int, n: int) -> Optional[str]:

@@ -36,7 +36,7 @@ import queue
 import threading
 from dataclasses import dataclass, field
 from typing import (Any, AsyncIterator, Callable, Dict, Iterator, List,
-                    Optional, Sequence, Set)
+                    Optional, Sequence, Set, Tuple)
 
 from repro.core import Request, RequestMetrics, SamplingParams
 from repro.core.request import RequestState
@@ -78,6 +78,9 @@ class RequestOutput:
     token_ids: List[int]
     finish_reason: Optional[str]
     metrics: RequestMetrics
+    # per token, where the backend computes them (the engine does): the two
+    # largest log-probs of the distribution it was drawn from
+    top_logprobs: List[Tuple[float, float]] = field(default_factory=list)
 
     @staticmethod
     def of(req: Request) -> "RequestOutput":
@@ -87,6 +90,7 @@ class RequestOutput:
             token_ids=list(req.output_token_ids),
             finish_reason=req.finish_reason,
             metrics=req.metrics,
+            top_logprobs=list(req.output_logprobs),
         )
 
     @property

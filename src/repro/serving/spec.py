@@ -33,8 +33,11 @@ class EngineSpec:
     """What model to serve and under which throttle policy.
 
     `reduced=True` builds the same-family reduced config (the CPU-sized
-    model every test and example runs); `reduced=False` uses the published
-    config on the production mesh factoring from the arch's plan (TPU).
+    model every test and example runs); `reduced=False` is the chip mode:
+    the published config at full depth in bf16, split over `stages`
+    pipeline stages on the first `stages` devices (one chip each), with the
+    KV pool sized from each device's free memory.  `stages` applies to the
+    chip mode only.
     `throttle` / `dims` are sparse overrides onto the backend's defaults
     (`ThrottleConfig` fields, `ServeDims` fields); `reduced_overrides` is
     passed to `make_reduced` (e.g. ``{"d_model": 128}``).
@@ -49,6 +52,7 @@ class EngineSpec:
 
     arch: str = "qwen1.5-0.5b"
     reduced: bool = True
+    stages: int = 1                 # chip mode: pipeline stages (devices)
     policy: str = "gllm"            # gllm | sarathi | no_wt | no_ut
     seed: int = 0
     throttle: Optional[Dict[str, Any]] = None
@@ -62,6 +66,11 @@ class EngineSpec:
     enable_prefix_caching: bool = False
 
     def __post_init__(self) -> None:
+        if self.stages < 1:
+            raise ValueError(f"EngineSpec.stages must be >= 1: {self.stages}")
+        if self.reduced and self.stages != 1:
+            raise ValueError("EngineSpec.stages applies to the chip mode "
+                             "(reduced=False) only")
         if self.dispatch not in ("sync", "async"):
             raise ValueError(
                 f"unknown dispatch {self.dispatch!r}; expected 'sync' or "

@@ -20,14 +20,11 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config, make_reduced
 from repro.core import SamplingParams, ThrottleConfig
-from repro.jax_compat import ensure_jax_compat
 from repro.models import transformer as tfm
 from repro.models.reference import greedy_generate
 from repro.models.serve import ServeDims
 from repro.runtime.engine import PipelineEngine
 from repro.runtime.router import ReplicaRouter
-
-ensure_jax_compat()   # jax may be imported after repro in combined runs
 
 
 def build_pair(arch="qwen1.5-0.5b", *, pages=256, page=8):

@@ -3,9 +3,7 @@
 tests' single-device jax runtime).
 
 These programs keep the `tensor` axis auto-sharded inside shard_map
-(partial-auto lowering); jax versions old enough to need the compat shims
-(repro/jax_compat.py) reject that on CPU with 'PartitionId ... not
-supported for SPMD partitioning', so the module skips there.
+(partial-auto lowering).
 """
 
 import os
@@ -14,14 +12,6 @@ import sys
 import textwrap
 
 import pytest
-
-from repro.jax_compat import is_shimmed
-
-pytestmark = pytest.mark.skipif(
-    is_shimmed(),
-    reason="partial-auto shard_map needs a native newer jax/XLA "
-           "(old SPMD partitioner: 'PartitionId instruction is not "
-           "supported')")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

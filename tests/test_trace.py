@@ -399,10 +399,6 @@ class TestEngineTrace:
         import dataclasses as dc
 
         import jax
-
-        from repro.jax_compat import ensure_jax_compat
-        ensure_jax_compat()          # jax imported after repro: shim now
-
         import jax.numpy as jnp
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
