@@ -9,6 +9,7 @@ writes ``BENCH_engine.json`` at the repo root:
     tokens_per_s        end-to-end decode throughput over the serve loop
     host_s_per_tick     host-side work per tick (prepare/meta/fresh/dispatch)
     readback_s_per_tick host time *blocked* on device token readback
+                        (`tick.readback_wait`)
     host_wait_per_tick  the sum — everything the host cannot overlap
     padded_ratio        padded tokens / (scheduled + padded) per class
     scanned_pages       KV pages the attention scan walked (bucket width)
@@ -143,6 +144,7 @@ def run_variant(name: str, params_cache: dict, waves, *,
     compiles_final = eng.backend.compile_count()
     sched = st.scheduled_prefill + st.scheduled_decode
     padded = st.padded_prefill + st.padded_decode
+    readback = st.phases.get("tick.readback_wait", 0.0)
     return {
         "outputs": [r.output_token_ids for r in reqs],
         "report": {
@@ -151,9 +153,9 @@ def run_variant(name: str, params_cache: dict, waves, *,
             "wall_s": round(wall, 4),
             "tokens_per_s": round(st.tokens_out / wall, 2) if wall else None,
             "host_s_per_tick": round(st.host_s / max(st.ticks, 1), 6),
-            "readback_s_per_tick": round(st.device_s / max(st.ticks, 1), 6),
+            "readback_s_per_tick": round(readback / max(st.ticks, 1), 6),
             "host_wait_per_tick": round(
-                (st.host_s + st.device_s) / max(st.ticks, 1), 6),
+                (st.host_s + readback) / max(st.ticks, 1), 6),
             "padded_prefill": st.padded_prefill,
             "padded_decode": st.padded_decode,
             "scheduled_prefill": st.scheduled_prefill,

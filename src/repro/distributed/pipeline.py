@@ -401,19 +401,21 @@ def build_serve_tick(cfg: ArchConfig, mesh: Mesh, dims: ServeDims,
             params["stages"], caches, carry["xp"], carry["xd"], meta,
             fresh["xp"], fresh["xd"])
         h_last = sample[-1]                       # [D*(Sp+Sd), d]
-        logits = tfm.head_apply(cfg, params, h_last).astype(jnp.float32)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        if sampling is not None:
-            temps = sampling["temps"].astype(jnp.float32)
-            key = jax.random.key(sampling["seed"])
-            scaled = logits / jnp.maximum(temps, 1e-3)[:, None]
-            drawn = jax.random.categorical(key, scaled, axis=-1) \
-                .astype(jnp.int32)
-            tokens = jnp.where(temps > 0.0, drawn, greedy)
-        else:
-            tokens = greedy
-        logprobs = jax.nn.log_softmax(logits, axis=-1)
-        top2 = jax.lax.top_k(logprobs, 2)[0]
+        with jax.named_scope("head"):
+            logits = tfm.head_apply(cfg, params, h_last).astype(jnp.float32)
+        with jax.named_scope("sample"):
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            if sampling is not None:
+                temps = sampling["temps"].astype(jnp.float32)
+                key = jax.random.key(sampling["seed"])
+                scaled = logits / jnp.maximum(temps, 1e-3)[:, None]
+                drawn = jax.random.categorical(key, scaled, axis=-1) \
+                    .astype(jnp.int32)
+                tokens = jnp.where(temps > 0.0, drawn, greedy)
+            else:
+                tokens = greedy
+            logprobs = jax.nn.log_softmax(logits, axis=-1)
+            top2 = jax.lax.top_k(logprobs, 2)[0]
         return ({"xp": xp_n, "xd": xd_n}, caches_n, tokens, top2)
 
     specs = {

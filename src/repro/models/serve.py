@@ -354,16 +354,19 @@ def _paged_self_attention(cfg, p, xs, cache, meta, dims: ServeDims,
         tables, ctx = meta["d_block_tables"], meta["d_context_lens"]
         pages, offs = meta["d_slot_pages"][:, None], meta["d_slot_offsets"][:, None]
 
-    q, k, v = _qkv_rows(cfg, p, xs, positions, prefix)
-    new_kv = jnp.stack([k, v], axis=2)                    # [S, T, 2, KH, hd]
-    cache = attn.write_kv_pages(cache, new_kv, pages, offs, valid)
+    with jax.named_scope("qkv"):
+        q, k, v = _qkv_rows(cfg, p, xs, positions, prefix)
+    with jax.named_scope("kv_write"):
+        new_kv = jnp.stack([k, v], axis=2)                # [S, T, 2, KH, hd]
+        cache = attn.write_kv_pages(cache, new_kv, pages, offs, valid)
     merge_axis = "data" if (dims.seq_shard and not is_prefill) else None
     shard_info = None
     if merge_axis is not None:
         shard_info = (jax.lax.axis_index("data"), jax.lax.psum(1, "data"))
-    o = attn.paged_attention(q, cache, tables, ctx, positions,
-                             pages_per_block=_pages_per_block(),
-                             merge_axis=merge_axis, shard_info=shard_info)
+    with jax.named_scope("attention"):
+        o = attn.paged_attention(q, cache, tables, ctx, positions,
+                                 pages_per_block=_pages_per_block(),
+                                 merge_axis=merge_axis, shard_info=shard_info)
     o = o.reshape(o.shape[:-2] + (-1,)) @ p[f"{prefix}wo"]
     return o, cache
 
@@ -385,20 +388,23 @@ def _paged_mla_attention(cfg, p, xs, cache, meta, dims: ServeDims,
         tables, ctx = meta["d_block_tables"], meta["d_context_lens"]
         pages, offs = meta["d_slot_pages"][:, None], meta["d_slot_offsets"][:, None]
 
-    cq = rmsnorm(xs @ p["w_dq"], p["q_norm_g"], cfg.norm_eps)
-    q = (cq @ p["w_uq"]).reshape(S, T, H, dn + dr)
-    q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta)
-    q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
-    ckv_full = xs @ p["w_dkv"]
-    ckv = rmsnorm(ckv_full[..., :klr], p["kv_norm_g"], cfg.norm_eps)
-    k_rope = apply_rope(ckv_full[..., None, klr:], positions,
-                        cfg.rope_theta)[..., 0, :]
-    lat = jnp.concatenate([ckv, k_rope], axis=-1)          # [S, T, klr+dr]
-    cache = attn.write_kv_pages(cache, lat, pages, offs, valid)
-    o = attn.paged_attention_mla(
-        q, cache, p["w_ukv"], tables, ctx, positions,
-        kv_lora_rank=klr, qk_nope_dim=dn, v_head_dim=dv,
-        pages_per_block=_pages_per_block())
+    with jax.named_scope("qkv"):
+        cq = rmsnorm(xs @ p["w_dq"], p["q_norm_g"], cfg.norm_eps)
+        q = (cq @ p["w_uq"]).reshape(S, T, H, dn + dr)
+        q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+        ckv_full = xs @ p["w_dkv"]
+        ckv = rmsnorm(ckv_full[..., :klr], p["kv_norm_g"], cfg.norm_eps)
+        k_rope = apply_rope(ckv_full[..., None, klr:], positions,
+                            cfg.rope_theta)[..., 0, :]
+        lat = jnp.concatenate([ckv, k_rope], axis=-1)      # [S, T, klr+dr]
+    with jax.named_scope("kv_write"):
+        cache = attn.write_kv_pages(cache, lat, pages, offs, valid)
+    with jax.named_scope("attention"):
+        o = attn.paged_attention_mla(
+            q, cache, p["w_ukv"], tables, ctx, positions,
+            kv_lora_rank=klr, qk_nope_dim=dn, v_head_dim=dv,
+            pages_per_block=_pages_per_block())
     return o.reshape(S, T, H * dv) @ p["wo"], cache
 
 
@@ -600,16 +606,17 @@ def block_apply_serve(cfg: ArchConfig, kind: BlockKind, p, xp, xd, cache,
         parts.append(norm("ln2", xd).reshape(-1, cfg.d_model))
         valid_parts.append((meta["d_valid"] > 0))
     flat = jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
-    if kind in (BlockKind.ATTN_MOE, BlockKind.MAMBA_MOE):
-        ep = "data" if cfg.plan.ep_over_data else None
-        row_valid = (jnp.concatenate(valid_parts)
-                     if len(valid_parts) > 1 else valid_parts[0])
-        y, _ = moe_lib.moe_apply(flat, p, top_k=cfg.num_experts_per_tok,
-                                 ep_axis=ep,
-                                 capacity_factor=cfg.moe_capacity_factor,
-                                 row_valid=row_valid)
-    else:
-        y = mlp_apply(flat, p, cfg.act)
+    with jax.named_scope("mlp"):
+        if kind in (BlockKind.ATTN_MOE, BlockKind.MAMBA_MOE):
+            ep = "data" if cfg.plan.ep_over_data else None
+            row_valid = (jnp.concatenate(valid_parts)
+                         if len(valid_parts) > 1 else valid_parts[0])
+            y, _ = moe_lib.moe_apply(flat, p, top_k=cfg.num_experts_per_tok,
+                                     ep_axis=ep,
+                                     capacity_factor=cfg.moe_capacity_factor,
+                                     row_valid=row_valid)
+        else:
+            y = mlp_apply(flat, p, cfg.act)
     off = 0
     if has_p:
         n = Sp * xp.shape[1]
@@ -667,20 +674,22 @@ def stage_forward_serve(cfg: ArchConfig, stage_params, caches, xp, xd, meta,
             def scan_body(carry, inp):
                 act, acc = carry
                 pl, li = inp
-                cl = jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(
-                        a, li, 0, keepdims=False), acc) if acc else None
+                with jax.named_scope("kv_slice"):
+                    cl = jax.tree.map(
+                        lambda a: jax.lax.dynamic_index_in_dim(
+                            a, li, 0, keepdims=False), acc) if acc else None
                 act, nc = apply_one(act, pl, cl, li)
                 if acc:
-                    acc = jax.tree.map(
-                        lambda full, upd:
-                        jax.lax.dynamic_update_index_in_dim(full, upd, li, 0),
-                        acc, nc)
+                    with jax.named_scope("kv_update"):
+                        acc = jax.tree.map(
+                            lambda a, u: jax.lax.dynamic_update_index_in_dim(
+                                a, u, li, 0), acc, nc)
                 return (act, acc), None
 
-            ((xp, xd, enc_cache), acc), _ = jax.lax.scan(
-                scan_body, ((xp, xd, enc_cache), cache_i),
-                (p, jnp.arange(bs.repeat)))
+            with jax.named_scope("layers"):
+                ((xp, xd, enc_cache), acc), _ = jax.lax.scan(
+                    scan_body, ((xp, xd, enc_cache), cache_i),
+                    (p, jnp.arange(bs.repeat)))
             if acc:
                 new_caches[key] = acc
         layer_offset += bs.repeat

@@ -18,11 +18,31 @@ kind without touching the tick loop.
 
 from __future__ import annotations
 
+import time
 from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Callable, Deque, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Deque, Iterator, List, Optional, Sequence,
+                    Tuple)
+
+from jax.profiler import TraceAnnotation
 
 from repro.core import PipelineScheduler, Request, ScheduledBatch
+
+
+class Phases(dict):
+    """Seconds spent in each named phase of the tick path (phase name ->
+    seconds, accumulated).  `span(name)` times a phase on the host clock
+    and opens a `jax.profiler.TraceAnnotation` of the same name, which the
+    profiler records only while a trace is active — so a device trace's
+    idle gaps can be put down to the phase the host was in."""
+
+    @contextmanager
+    def span(self, name: str) -> Iterator[None]:
+        t0 = time.perf_counter()
+        with TraceAnnotation(name):
+            yield
+        self[name] = self.get(name, 0.0) + time.perf_counter() - t0
 
 
 @dataclass
@@ -40,10 +60,13 @@ class ExecResult:
     (the live engine) leave it None; the simulator and trace replay fill it,
     and `CostModel.fit_from_trace` calibrates against it.
 
-    `host_s` optionally reports the host-side time this tick spent outside
-    device execution (metadata assembly, embedding lookups, dispatch) — the
-    engine measures it, the simulator models it, and trace schema ≥ 1.3
-    records it so `RuntimeModel.fit_from_trace` can calibrate the overhead.
+    `host_s` optionally reports the host time this tick spent in the
+    backend's prepare and execute steps (metadata assembly, stacking,
+    embedding lookups, dispatch) — the engine measures it, the simulator
+    models it, and trace schema ≥ 1.3 records it so
+    `RuntimeModel.fit_from_trace` can calibrate the overhead.  The engine's
+    figure includes the host's wait for the embedding lookups, which queue
+    behind the tick in flight (`tick.embed_wait` in `EngineStats.phases`).
 
     **Deferred form.**  A backend that dispatches asynchronously returns the
     result with `pending` set: a thunk that blocks on the device readback and
@@ -189,6 +212,13 @@ class TickLoop:
     placement (the Table-1 equivalence property).  Sync mode stays the
     default — the simulator and trace replay/record paths depend on results
     materializing within their own tick.
+
+    **Counters.**  `phases` holds the host seconds of the loop's own phases
+    (`tick.schedule`, `tick.prepare`, `tick.retire`), each also a profiler
+    span.  `retired_ready` counts exiting batches retired before the next
+    schedule (in sync mode every one, in async mode those whose readback
+    the probe found done); `retired_late` counts those retired only after
+    the next tick was scheduled — their requests missed that tick.
     """
 
     def __init__(self, scheduler: PipelineScheduler, backend: ExecutionBackend,
@@ -207,6 +237,9 @@ class TickLoop:
         # async mode: the exiting batch of the *previous* tick, its readback
         # still deferred — retired at the top of the next step
         self._pending: Optional[Tuple[int, ExecResult]] = None
+        self.phases = Phases()
+        self.retired_ready = 0
+        self.retired_late = 0
 
     # ------------------------------------------------------------------ state
     @property
@@ -239,20 +272,22 @@ class TickLoop:
             # cohorts, inflating the tick count (~51 vs 36 on the bench
             # workload).  When the probe says "still running", the parked
             # result waits as before and the overlap is preserved.
-            finished_early = self._retire_pending(now)
-        batch = self.scheduler.schedule(now)
-        if batch.is_empty:
-            # nothing resident this tick: retire the empty batch immediately
-            self.scheduler.complete(batch.batch_id, [], now)
-            entry: Tuple[Optional[int], Any] = (None, self.backend.prepare(None))
-        else:
-            entry = (batch.batch_id, self.backend.prepare(batch))
+            finished_early = self._retire_pending(now, late=False)
+        with self.phases.span("tick.schedule"):
+            batch = self.scheduler.schedule(now)
+            if batch.is_empty:
+                # nothing resident: retire the empty batch immediately
+                self.scheduler.complete(batch.batch_id, [], now)
+        with self.phases.span("tick.prepare"):
+            entry: Tuple[Optional[int], Any] = (
+                (None, self.backend.prepare(None)) if batch.is_empty
+                else (batch.batch_id, self.backend.prepare(batch)))
         self.last_tick_empty = batch.is_empty
         if (self.async_dispatch and batch.is_empty and not self._ring_busy
                 and self._pending is not None):
             # nothing to execute — only the deferred batch remains; retire it
             # without paying a bubble device tick
-            return finished_early + self._retire_pending(now)
+            return finished_early + self._retire_pending(now, late=True)
         # Rotate: the new batch enters stage 0; the entry reaching the ring's
         # tail is the one executing its LAST stage this tick — its results
         # materialize when `execute` returns.  (For depth 1 that is this
@@ -267,18 +302,20 @@ class TickLoop:
             # tick's exiting batch — its readback has had a full device tick
             # to complete, so the resolve below rarely blocks — and park this
             # tick's exiting batch until the next step.
-            finished = (self._retire_pending(now)
+            finished = (self._retire_pending(now, late=True)
                         if self._pending is not None else [])
             if exiting_id is not None:
                 self._pending = (exiting_id, result)
             self.ring[-1] = (None, self.backend.prepare(None))
             return finished_early + finished
 
-        result.resolve()
-        if exiting_id is None:
-            return []
-        finished = self._retire(exiting_id, result.tokens,
-                                result.completed_at)
+        with self.phases.span("tick.retire"):
+            result.resolve()
+            if exiting_id is None:
+                return []
+            self.retired_ready += 1
+            finished = self._retire(exiting_id, result.tokens,
+                                    result.completed_at)
         # the retired entry is never read again (the next push would drop
         # it); clear it so `busy` reflects only live work
         self.ring[-1] = (None, self.backend.prepare(None))
@@ -294,14 +331,20 @@ class TickLoop:
         return out
 
     # ----------------------------------------------------------------- retire
-    def _retire_pending(self, now: float) -> List[Request]:
+    def _retire_pending(self, now: float, *, late: bool) -> List[Request]:
         """Force the deferred readback of the previous tick's exiting batch
         and retire it.  `now` (resolve-time clock) is the completion time —
-        the tokens materialized no later than this."""
+        the tokens materialized no later than this.  `late`: the next tick
+        has already been scheduled (counted in `retired_late`)."""
         assert self._pending is not None
         bid, result = self._pending
         self._pending = None
-        return self._retire(bid, result.resolve(), now)
+        if late:
+            self.retired_late += 1
+        else:
+            self.retired_ready += 1
+        with self.phases.span("tick.retire"):
+            return self._retire(bid, result.resolve(), now)
 
     def _retire(self, batch_id: int, tokens: Sequence[int],
                 now: float) -> List[Request]:

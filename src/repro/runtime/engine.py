@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import itertools
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -46,7 +46,7 @@ from repro.core import (
 from repro.models import serve as serve_lib
 from repro.models import transformer as tfm
 from repro.models.serve import ServeDims
-from repro.runtime.core import ExecResult, ExecutionBackend, TickLoop
+from repro.runtime.core import ExecResult, ExecutionBackend, Phases, TickLoop
 
 
 class SlotAllocator:
@@ -82,8 +82,12 @@ class EngineStats:
     scanned_pages: int = 0      # KV pages the attention scan walks per tick
     live_pages: int = 0         # KV pages actually holding context
     host_s: float = 0.0         # host-side per-tick work (meta/fresh/dispatch)
-    device_s: float = 0.0       # host time *blocked* on device readback
     last_bucket: Optional[Dict[str, int]] = None  # selected serve shape
+    # host seconds per phase of `execute` (tick.stack, tick.embed,
+    # tick.sampling, tick.dispatch) and per wait on the device
+    # (tick.embed_wait inside tick.embed; tick.readback_wait, the exiting
+    # batch's token readback, inside the loop's tick.retire)
+    phases: Phases = field(default_factory=Phases)
 
 
 class JaxBackend(ExecutionBackend):
@@ -126,8 +130,10 @@ class JaxBackend(ExecutionBackend):
         self._build_serve_tick = build_serve_tick
         self._ticks: Dict[Tuple[int, int, int, int, int], Any] = {}
 
-        self._embed = jax.jit(
-            lambda p, t: jnp.take(p["embed"]["tok"], t, axis=0))
+        def embed(p, t):
+            with jax.named_scope("embed"):
+                return jnp.take(p["embed"]["tok"], t, axis=0)
+        self._embed = jax.jit(embed)
         # Every tick input is placed with an explicit sharding on the
         # engine's mesh, so its type is the same whichever mesh context the
         # caller is in (inside a `jax.set_mesh` block or outside any) and
@@ -288,13 +294,17 @@ class JaxBackend(ExecutionBackend):
     def execute(self, ring: Sequence[Tuple[Optional[int], Any]],
                 exiting_id: Optional[int], now: float) -> ExecResult:
         t0 = time.perf_counter()
-        bucket = self._select_bucket(ring)
-        meta_dev = self._stack_meta(ring, bucket)
-        entering = (self.scheduler.get_batch(ring[0][0])
-                    if ring[0][0] is not None else None)
-        fresh = self._build_fresh(entering, bucket)
-        sampling = self._build_sampling(exiting_id, bucket)
-        with jax.set_mesh(self.mesh):
+        phases = self.stats.phases
+        with phases.span("tick.stack"):
+            bucket = self._select_bucket(ring)
+            meta_dev = self._stack_meta(ring, bucket)
+        with phases.span("tick.embed"):
+            entering = (self.scheduler.get_batch(ring[0][0])
+                        if ring[0][0] is not None else None)
+            fresh = self._build_fresh(entering, bucket)
+        with phases.span("tick.sampling"):
+            sampling = self._build_sampling(exiting_id, bucket)
+        with phases.span("tick.dispatch"), jax.set_mesh(self.mesh):
             self.carry, self.caches, tokens, top_lp = self._get_tick(bucket)(
                 self.params, self.caches, self.carry, meta_dev, fresh,
                 sampling)
@@ -336,13 +346,12 @@ class JaxBackend(ExecutionBackend):
         d_off = bucket.Sp
 
         def readback() -> List[int]:
-            t1 = time.perf_counter()
-            host = np.asarray(tokens)       # blocks until the tick finishes
-            self.stats.device_s += time.perf_counter() - t1
+            with phases.span("tick.readback_wait"):
+                host = np.asarray(tokens)   # blocks until the tick finishes
+                lps = np.asarray(top_lp)
             rows = prefill_rows + [d_off + j for j in range(n_decode)]
             reqs = [exiting.prefill[i].request for i in prefill_rows]
             reqs += [seq.request for seq in exiting.decode]
-            lps = np.asarray(top_lp)
             for req, r in zip(reqs, rows):
                 # aligned with output_token_ids once the scheduler records
                 # this row's token (a discarded token's entry is overwritten)
@@ -533,9 +542,12 @@ class JaxBackend(ExecutionBackend):
             d_tok[s, 0] = seq.request.effective_prompt[seq.start_pos]
         # the embed jit keys on the ambient mesh context like any other
         # program: run it under the same scope as the tick call so the
-        # warm-time and serve-time signatures coincide
+        # warm-time and serve-time signatures coincide.  The lookups queue
+        # behind the tick in flight, so reading them back is the host's
+        # wait on the device (tick.embed_wait)
+        wait = self.stats.phases.span
         if dims.Sp:
-            with jax.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh), wait("tick.embed_wait"):
                 emb = np.asarray(self._embed(
                     self.params, jax.device_put(p_tok, self._repl_sh)),
                     np.float32)
@@ -546,7 +558,7 @@ class JaxBackend(ExecutionBackend):
                 if enc is not None:
                     xp[s, : enc.shape[0], :] = enc
         if dims.Sd:
-            with jax.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh), wait("tick.embed_wait"):
                 xd[:, 0, :] = np.asarray(
                     self._embed(self.params,
                                 jax.device_put(d_tok, self._repl_sh)),
