@@ -10,11 +10,14 @@ grid walks (seq, q-block, page) and the KV BlockSpec *fetches page
 `tables[s, b]` from HBM into VMEM* while the previous page is being
 consumed (hardware double-buffering replaces the GPU's manual smem staging).
 Online softmax state lives in VMEM scratch across the minor (page) grid dim.
-Tiles meet the TPU block rule (the last two block dims divisible by (8, 128)
-or equal to the array's) by taking whole (KH|H, D) trailing dims and
-q-blocks of 128 rows (or all of TQ); they are not (8,128)-aligned in
-general — with D = 64 the last dim is half a lane.  The query positions
-travel as [S, 1, TQ] so their block's trailing dims are (1, tq).
+The pool is stored lane-dense, [P, page, KH·2·D] with the lanes ordered
+[KH, 2, D] (each head's K then V), so a page block (1, page, KH·2·D) is the
+array's own row-major (8, 128) tiling and XLA has no layout to convert
+between the stored pool and the kernel (DESIGN.md §6); head kh's K sits in
+lanes [2·kh·D, (2·kh+1)·D) and its V in the next D.  The q and output
+blocks take whole (H, D) trailing dims and q-blocks of 128 rows (or all of
+TQ).  The query positions travel as [S, 1, TQ] so their block's trailing
+dims are (1, tq).
 """
 
 from __future__ import annotations
@@ -38,7 +41,7 @@ def _kernel(
     # inputs
     q_ref,                 # [1, TQ, H, D]
     qpos_ref,              # [1, 1, TQ] int32 global positions
-    kv_ref,                # [1, page, 2, KH, D] — page tables[s, b]
+    kv_ref,                # [1, page, KH·2·D] — page tables[s, b]
     # outputs
     o_ref,                 # [1, TQ, H, D]
     # scratch
@@ -70,8 +73,13 @@ def _kernel(
         TQ, H, D = q.shape
         KH = kv_heads
         G = H // KH
-        kv = kv_ref[0].astype(jnp.float32)              # [page, 2, KH, D]
-        k, v = kv[:, 0], kv[:, 1]                       # [page, KH, D]
+
+        def k_of(kh):                                   # [page, D]
+            return kv_ref[0, :, 2 * kh * D:(2 * kh + 1) * D].astype(jnp.float32)
+
+        def v_of(kh):                                   # [page, D]
+            return kv_ref[0, :, (2 * kh + 1) * D:(2 * kh + 2) * D].astype(
+                jnp.float32)
 
         kpos = b * page + jax.lax.broadcasted_iota(jnp.int32, (page,), 0)
         ctx = ctx_ref[s]
@@ -82,7 +90,7 @@ def _kernel(
         parts = []
         for kh in range(KH):
             qg = q[:, kh * G:(kh + 1) * G, :].reshape(TQ * G, D)
-            sc = jax.lax.dot_general(qg, k[:, kh, :],
+            sc = jax.lax.dot_general(qg, k_of(kh),
                                      (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
             parts.append(sc.reshape(TQ, G, page))
@@ -99,7 +107,7 @@ def _kernel(
         pv_parts = []
         for kh in range(KH):
             pg = p[:, kh * G:(kh + 1) * G, :].reshape(TQ * G, page)
-            pv = jax.lax.dot_general(pg, v[:, kh, :],
+            pv = jax.lax.dot_general(pg, v_of(kh),
                                      (((1,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
             pv_parts.append(pv.reshape(TQ, G, D))
@@ -115,7 +123,7 @@ def _kernel(
 @functools.partial(jax.jit, static_argnames=("interpret", "q_block"))
 def paged_flash_attention(
     q: jax.Array,            # [S, TQ, H, D]
-    kv_pages: jax.Array,     # [P, page, 2, KH, D]
+    kv_pages: jax.Array,     # [P, page, KH·2·D], lanes [KH, 2, D]
     block_tables: jax.Array, # [S, B] int32
     context_lens: jax.Array, # [S] int32
     q_positions: jax.Array,  # [S, TQ] int32
@@ -124,7 +132,8 @@ def paged_flash_attention(
     interpret: bool = False,
 ) -> jax.Array:
     S, TQ, H, D = q.shape
-    P, page, _, KH, _ = kv_pages.shape
+    P, page, L = kv_pages.shape
+    KH = L // (2 * D)
     B = block_tables.shape[1]
     tq = min(q_block, TQ)
     assert TQ % tq == 0, (TQ, tq)
@@ -145,7 +154,7 @@ def paged_flash_attention(
 
     def kv_index(s, qb, b, tables, ctx, live):
         bb = jnp.minimum(b, jnp.maximum(live[s] - 1, 0))
-        return (tables[s * B + bb], 0, 0, 0, 0)
+        return (tables[s * B + bb], 0, 0)
 
     kernel = functools.partial(_kernel, kv_heads=KH, page=page, num_pages=B)
     out = pl.pallas_call(
@@ -156,7 +165,7 @@ def paged_flash_attention(
             in_specs=[
                 pl.BlockSpec((1, tq, H, D), q_index),
                 pl.BlockSpec((1, 1, tq), pos_index),
-                pl.BlockSpec((1, page, 2, KH, D), kv_index),
+                pl.BlockSpec((1, page, L), kv_index),
             ],
             out_specs=pl.BlockSpec((1, tq, H, D), q_index),
             scratch_shapes=[

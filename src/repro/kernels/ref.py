@@ -8,18 +8,19 @@ import jax.numpy as jnp
 
 def paged_flash_attention_ref(
     q: jax.Array,             # [S, TQ, H, D]
-    kv_pages: jax.Array,      # [P, page, 2, KH, D]
+    kv_pages: jax.Array,      # [P, page, KH·2·D], lanes [KH, 2, D]
     block_tables: jax.Array,  # [S, B]
     context_lens: jax.Array,  # [S]
     q_positions: jax.Array,   # [S, TQ]
 ) -> jax.Array:
     S, TQ, H, D = q.shape
-    _, page, _, KH, _ = kv_pages.shape
+    page = kv_pages.shape[1]
+    KH = kv_pages.shape[-1] // (2 * D)
     B = block_tables.shape[1]
     G = H // KH
-    gathered = kv_pages[block_tables]                  # [S, B, page, 2, KH, D]
-    kv = gathered.reshape(S, B * page, 2, KH, D).astype(jnp.float32)
-    k, v = kv[:, :, 0], kv[:, :, 1]
+    gathered = kv_pages[block_tables]                  # [S, B, page, KH·2·D]
+    kv = gathered.reshape(S, B * page, KH, 2, D).astype(jnp.float32)
+    k, v = kv[:, :, :, 0], kv[:, :, :, 1]
     kpos = jnp.arange(B * page)
     mask = (kpos[None, None, :] < context_lens[:, None, None]) & \
            (kpos[None, None, :] <= q_positions[:, :, None])     # [S, TQ, Bp]

@@ -150,15 +150,31 @@ def cross_attention(
 # Paged attention (serving): query rows attend to block-table pages
 # ----------------------------------------------------------------------------
 
+def pack_kv(k: jax.Array, v: jax.Array) -> jax.Array:
+    """k, v [..., KH, D] -> the paged pool's lane-dense rows [..., KH·2·D],
+    head-major: head kh's K in lanes [2·kh·D, (2·kh+1)·D), its V in the
+    next D (DESIGN.md §6)."""
+    kv = jnp.stack([k, v], axis=-2)                   # [..., KH, 2, D]
+    return kv.reshape(kv.shape[:-3] + (-1,))
+
+
+def split_kv(rows: jax.Array, head_dim: int) -> Tuple[jax.Array, jax.Array]:
+    """Inverse of `pack_kv`: [..., KH·2·D] -> k, v [..., KH, D]."""
+    kv = rows.reshape(rows.shape[:-1] + (-1, 2, head_dim))
+    return kv[..., 0, :], kv[..., 1, :]
+
+
 def write_kv_pages(
-    cache: jax.Array,                 # [Pages, page, 2, KH, D] (or [..., C] MLA)
-    new_kv: jax.Array,                # [S, C, 2, KH, D] / [S, C, Cdim]
+    cache: jax.Array,                 # [Pages, page, KH·2·D] (MLA: [..., C])
+    new_kv: jax.Array,                # [S, C, KH·2·D] / [S, C, Cdim]
     slot_pages: jax.Array,            # [S, C] int32 destination page per token
     slot_offsets: jax.Array,          # [S, C] int32 offset within page
     valid: jax.Array,                 # [S, C] bool (padding rows don't write)
 ) -> jax.Array:
     flat_kv = new_kv.reshape((-1,) + new_kv.shape[2:])
-    pages = jnp.where(valid, slot_pages, -1).reshape(-1)   # OOB => dropped
+    # padded rows aim past the pool and are dropped (-1 would wrap to the
+    # last page: jnp indexing normalizes negative indices before the drop)
+    pages = jnp.where(valid, slot_pages, cache.shape[0]).reshape(-1)
     offs = slot_offsets.reshape(-1)
     return cache.at[pages, offs].set(flat_kv, mode="drop")
 
@@ -175,7 +191,7 @@ def _check_table_alignment(Bmax: int, pages_per_block: int) -> None:
 
 def paged_attention(
     q: jax.Array,                     # [S, C, H, D] (C==1 for decode)
-    cache: jax.Array,                 # [Pages, page, 2, KH, D]
+    cache: jax.Array,                 # [Pages, page, KH·2·D], head-major
     block_tables: jax.Array,          # [S, Bmax] int32
     context_lens: jax.Array,          # [S] int32 (incl. this step's tokens)
     q_positions: jax.Array,           # [S, C] int32 global positions
@@ -193,7 +209,7 @@ def paged_attention(
     """
     S, Bmax = block_tables.shape
     page = cache.shape[1]
-    KH, D = cache.shape[-2], cache.shape[-1]
+    D = q.shape[-1]
     _check_table_alignment(Bmax, pages_per_block)
     n_blocks = Bmax // pages_per_block
     Bk = pages_per_block * page
@@ -210,9 +226,8 @@ def paged_attention(
     def kv_blk(i):
         tabs = jax.lax.dynamic_slice_in_dim(block_tables, i * pages_per_block,
                                             pages_per_block, axis=1)  # [S, pb]
-        gathered = cache[tabs]                 # [S, pb, page, 2, KH, D]
-        kv = gathered.reshape(S, Bk, 2, KH, D)
-        kb, vb = kv[:, :, 0], kv[:, :, 1]
+        gathered = cache[tabs]                 # [S, pb, page, KH·2·D]
+        kb, vb = split_kv(gathered.reshape(S, Bk, -1), D)   # [S, Bk, KH, D]
         base = (i * pages_per_block + jnp.arange(pages_per_block)) * page
         kpos = (base[:, None] + jnp.arange(page)[None, :]).reshape(Bk)  # [Bk]
         if shard_info is not None:

@@ -5,7 +5,8 @@ Layouts (per pipeline stage, per data replica — both mesh axes are manual
 inside the serving tick):
   prefill payload  xp [Sp, C, d]    (whisper: [Sp, Te + C, d], enc slice first)
   decode payload   xd [Sd, 1, d]
-  paged KV         [R, pages, page, 2, KH, hd]   (R = block repeat)
+  paged KV         [R, pages, page, KH·2·hd]     (R = block repeat; lanes
+                   head-major [KH, 2, hd]: each head's K then V)
   MLA latent KV    [R, pages, page, klr + dr]
   mamba state      conv [R, slots, dc-1, di], ssm [R, slots, di, ds]
   rwkv state       tm_x/cm_x [R, slots, d], wkv [R, slots, H, hk, hv]
@@ -228,8 +229,11 @@ def block_cache_defs(cfg: ArchConfig, kind: BlockKind, dims: ServeDims,
     tp_heads = max(1, cfg.num_kv_heads)
     out: Dict[str, Tuple[Tuple[int, ...], P]] = {}
     if kind in (BlockKind.ATTN_MLP, BlockKind.ATTN_MOE, BlockKind.DEC_LAYER):
-        out["kv"] = ((R, dims.pages, dims.page, 2, tp_heads, cfg.head_dim),
-                     P(None, "data", None, None, "tensor", None))
+        # lane-dense: a token's K and V for every head on the minor axis, so
+        # the stored layout is the kernel's tile layout (DESIGN.md §6); a
+        # `tensor` split of the head-major lanes takes whole heads
+        out["kv"] = ((R, dims.pages, dims.page, tp_heads * 2 * cfg.head_dim),
+                     P(None, "data", None, "tensor"))
     elif kind == BlockKind.MLA_MLP:
         out["kv"] = ((R, dims.pages, dims.page,
                       cfg.kv_lora_rank + cfg.qk_rope_dim),
@@ -357,8 +361,8 @@ def _paged_self_attention(cfg, p, xs, cache, meta, dims: ServeDims,
     with jax.named_scope("qkv"):
         q, k, v = _qkv_rows(cfg, p, xs, positions, prefix)
     with jax.named_scope("kv_write"):
-        new_kv = jnp.stack([k, v], axis=2)                # [S, T, 2, KH, hd]
-        cache = attn.write_kv_pages(cache, new_kv, pages, offs, valid)
+        cache = attn.write_kv_pages(cache, attn.pack_kv(k, v), pages, offs,
+                                    valid)
     merge_axis = "data" if (dims.seq_shard and not is_prefill) else None
     shard_info = None
     if merge_axis is not None:

@@ -44,14 +44,14 @@ _REDUCED_DIMS = dict(Sp=1, C=32, Sd=8, pages=512, page=8, Bp=64, Bd=64,
 # page pool is whatever the device's memory holds after the weights and the
 # tick's temporaries (`chip_serve_dims`).
 _CHIP_DIMS = dict(Sp=2, C=512, Sd=64, page=16, Bp=256, Bd=256)
-# Device memory kept free for the tick program's temporaries other than the
-# KV copy (activations, [rows, vocab] logits, sampling) and for the
-# runtime's own buffers: about 0.9e9 bytes for Qwen1.5-0.5B on a v5e.
+# Device memory kept free for the tick program's temporaries (activations,
+# [rows, vocab] logits, sampling) and for the runtime's own buffers: about
+# 0.9e9 bytes for Qwen1.5-0.5B on a v5e.
 _CHIP_RESERVE_BYTES = 3 << 29
-# The tick's temporaries hold a copy of the KV pool at this many times its
-# bytes: the device keeps the pool page-minor, while the paged-attention
-# kernel reads it row-major, where a head_dim of 64 fills half of each
-# 128-lane tile (measured with `compiled.memory_analysis()` for a v5e).
+# Headroom kept beside the KV pool, at this many times its bytes.  The tick
+# copies none of the pool (it is stored in the kernel's own tiles, DESIGN.md
+# §6), so the room is unused; it stays until a change meant to resize the
+# pool, an input of Token Throttling, reclaims it.
 _KV_COPY_FACTOR = 2
 
 
@@ -192,8 +192,8 @@ def chip_serve_dims(cfg, free_bytes: int):
 
     The pool's unit is one KV page plus one state slot (``slots = pages``:
     every resident request holds at least one page, so slots never run out
-    first).  A unit costs its cache bytes on one device, plus the tick's
-    copy of them (`_KV_COPY_FACTOR`), and `_CHIP_RESERVE_BYTES` stay free for
+    first).  A unit costs its cache bytes on one device, plus the headroom
+    beside them (`_KV_COPY_FACTOR`), and `_CHIP_RESERVE_BYTES` stay free for
     the rest of the tick.
     """
     import jax

@@ -35,7 +35,7 @@ def _case(seed, ctx_max=None, TQ=1):
     P = S * B + 2
     ctx_max = ctx_max or B * PAGE
     q = jnp.asarray(rng.normal(size=(S, TQ, H, D)), jnp.float32)
-    cache = jnp.asarray(rng.normal(size=(P, PAGE, 2, KH, D)), jnp.float32)
+    cache = jnp.asarray(rng.normal(size=(P, PAGE, KH * 2 * D)), jnp.float32)
     tables = np.zeros((S, B), np.int32)
     ctx = rng.integers(TQ, ctx_max + 1, S).astype(np.int32)
     for s in range(S):

@@ -9,6 +9,7 @@ from repro.kernels import ref
 from repro.kernels.moe_gemm import fused_moe_ffn
 from repro.kernels.paged_attention import paged_flash_attention
 from repro.kernels.rwkv6_scan import rwkv6_chunked_scan
+from repro.models import attention as attn
 
 
 @pytest.mark.parametrize("S,TQ,H,KH,D,page,B", [
@@ -16,13 +17,15 @@ from repro.kernels.rwkv6_scan import rwkv6_chunked_scan
     (1, 16, 4, 4, 128, 8, 4),      # prefill chunk, MHA
     (3, 8, 8, 2, 64, 16, 8),       # prefill, deep tables
     (2, 1, 8, 8, 128, 8, 8),       # decode, MHA, D=128
+    (2, 1, 16, 16, 64, 16, 4),     # decode, MHA, D=64 (Qwen1.5-0.5B heads)
+    (1, 8, 16, 8, 128, 16, 4),     # prefill, GQA, D=128
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_flash_vs_oracle(S, TQ, H, KH, D, page, B, dtype):
     rng = np.random.default_rng(hash((S, TQ, H, D)) % 2**31)
     P = S * B + 2
     q = jnp.asarray(rng.normal(size=(S, TQ, H, D)), dtype)
-    kv = jnp.asarray(rng.normal(size=(P, page, 2, KH, D)), dtype)
+    kv = jnp.asarray(rng.normal(size=(P, page, KH * 2 * D)), dtype)
     tables = jnp.asarray(rng.permutation(P)[: S * B].reshape(S, B), jnp.int32)
     ctx = jnp.asarray(rng.integers(TQ, B * page + 1, S), jnp.int32)
     qpos = jnp.asarray(ctx[:, None] - TQ + np.arange(TQ)[None, :], jnp.int32)
@@ -39,7 +42,7 @@ def test_paged_flash_respects_context_len():
     rng = np.random.default_rng(0)
     S, TQ, H, KH, D, page, B = 1, 1, 2, 2, 64, 8, 4
     q = jnp.asarray(rng.normal(size=(S, TQ, H, D)), jnp.float32)
-    kv = jnp.asarray(rng.normal(size=(8, page, 2, KH, D)), jnp.float32)
+    kv = jnp.asarray(rng.normal(size=(8, page, KH * 2 * D)), jnp.float32)
     tables = jnp.asarray([[0, 1, 2, 3]], jnp.int32)
     qpos = jnp.asarray([[9]], jnp.int32)
     out_a = paged_flash_attention(q, kv, tables, jnp.asarray([10]), qpos,
@@ -50,6 +53,47 @@ def test_paged_flash_respects_context_len():
                                   interpret=True)
     np.testing.assert_allclose(np.asarray(out_a), np.asarray(out_b),
                                atol=1e-6)
+
+
+@pytest.mark.parametrize("T", [1, 8], ids=["decode", "prefill"])
+@pytest.mark.parametrize("D", [64, 128])
+def test_kv_pages_round_trip(T, D):
+    """`write_kv_pages` puts each valid row's heads in the lanes the kernel
+    reads (head kh's K at [2·kh·D, (2·kh+1)·D), its V in the next D) and
+    touches nothing else, padded rows included; the jnp gather returns the
+    K and V rows written."""
+    rng = np.random.default_rng(D + T)
+    S, KH, page, B = 3, 4, 4, 4
+    P = S * B                                           # every page in use
+    k = np.asarray(jnp.asarray(rng.normal(size=(S, T, KH, D)), jnp.bfloat16),
+                   np.float32)
+    v = np.asarray(jnp.asarray(rng.normal(size=(S, T, KH, D)), jnp.bfloat16),
+                   np.float32)
+    tables = rng.permutation(P).reshape(S, B).astype(np.int32)
+    start = rng.integers(0, B * page - T + 1, S)        # first row's position
+    pos = start[:, None] + np.arange(T)[None, :]        # [S, T]
+    valid = np.arange(T)[None, :] < np.array([T, max(1, T - 3), T])[:, None]
+    pages = np.take_along_axis(tables, pos // page, axis=1)
+    cache = attn.write_kv_pages(
+        jnp.zeros((P, page, KH * 2 * D), jnp.bfloat16),
+        attn.pack_kv(jnp.asarray(k, jnp.bfloat16), jnp.asarray(v, jnp.bfloat16)),
+        jnp.asarray(pages), jnp.asarray(pos % page), jnp.asarray(valid))
+
+    want = np.zeros((P, page, KH * 2 * D), np.float32)
+    for s, t in zip(*np.nonzero(valid)):
+        row = want[pages[s, t], pos[s, t] % page]
+        for kh in range(KH):
+            row[2 * kh * D:(2 * kh + 1) * D] = k[s, t, kh]
+            row[(2 * kh + 1) * D:(2 * kh + 2) * D] = v[s, t, kh]
+    np.testing.assert_array_equal(np.asarray(cache, np.float32), want)
+
+    kb, vb = attn.split_kv(cache[jnp.asarray(tables)].reshape(S, B * page, -1),
+                           D)                            # [S, B·page, KH, D]
+    for s, t in zip(*np.nonzero(valid)):
+        np.testing.assert_array_equal(np.asarray(kb[s, pos[s, t]], np.float32),
+                                      k[s, t])
+        np.testing.assert_array_equal(np.asarray(vb[s, pos[s, t]], np.float32),
+                                      v[s, t])
 
 
 @pytest.mark.parametrize("B,T,H,D,chunk", [
