@@ -45,12 +45,11 @@ def main(argv=None) -> None:
     from repro.serving import SamplingParams
 
     import correct
-    import weights
     from serve_loop import OpenLoop, attach_logprobs
     from traffic.generate import make_requests
 
     cfg, traffic = cell.config, cell.traffic
-    server, params = run.build_server(cfg, seeds[0])
+    server, params = run.build_server(cell, seeds[0])
     engine = server.replicas[0]
     like = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=a.sharding), params)
@@ -61,8 +60,8 @@ def main(argv=None) -> None:
     picked = {}
     for seed in seeds:
         engine.params = engine.backend.params = None
-        engine.params = engine.backend.params = weights.make_program_params(
-            cfg, seed, like)
+        engine.params = engine.backend.params = \
+            cell.model.make_program_params(cfg, seed, like)
         plan = make_requests(traffic, seed=seed, duration_s=w1,
                              vocab=cfg["vocab_size"])
         loop = OpenLoop(server, plan, SamplingParams, tag=f"s{seed}")
@@ -78,11 +77,11 @@ def main(argv=None) -> None:
     gc.collect()
     rows = []
     for seed in seeds:
-        params = weights.make_program_params(cfg, seed, like)
+        params = cell.model.make_program_params(cfg, seed, like)
         mismatches = correct.length_mismatches(picked[seed])
         row = {"seed": seed, "length_mismatches": mismatches}
         for side, control in (("program", None), (CONTROL, CONTROL)):
-            found = correct.compare(cfg, params, picked[seed],
+            found = correct.compare(cell.model, cfg, params, picked[seed],
                                     control=control)
             checks = run.compared(cfg, found, mismatches, 0)
             row[side] = dict(found, correct=all(

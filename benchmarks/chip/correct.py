@@ -20,8 +20,6 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
-import reference
-
 SAMPLE_REQUESTS = 8
 
 
@@ -51,20 +49,21 @@ def log_softmax(logits: np.ndarray) -> np.ndarray:
     return logits - m - np.log(np.exp(logits - m).sum(axis=1, keepdims=True))
 
 
-def compare(cfg: Dict, params: Dict, picked: Sequence, *,
+def compare(model, cfg: Dict, params: Dict, picked: Sequence, *,
             control=None) -> Dict:
-    """Both numbers over every served token of `picked`.  With `control`
-    ("fp8") the reference at that precision stands in for the
+    """Both numbers over every served token of `picked`, against the plain
+    reference of `model` (the configuration's model module).  With
+    `control` ("fp8") the reference at that precision stands in for the
     program: at each position of the same prompts and served tokens its own
     best token, and its log-prob of it, are judged in place of the served
     ones."""
     gap = err = 0.0
     count = 0
     for rec in picked:
-        ref = reference.teacher_forced_logits(cfg, params,
-                                              rec.planned.prompt, rec.tokens)
+        ref = model.teacher_forced_logits(cfg, params, rec.planned.prompt,
+                                          rec.tokens)
         if control:
-            low = log_softmax(reference.teacher_forced_logits(
+            low = log_softmax(model.teacher_forced_logits(
                 cfg, params, rec.planned.prompt, rec.tokens, low=control))
             tokens = low.argmax(axis=1)
             reported = low.max(axis=1)

@@ -1,10 +1,8 @@
 """Paged-attention kernel: the least time the chip needs for the kernel's
 work in the traced sub-window (the larger of its operations over the bf16
-peak and its bytes over the HBM peak, `counts.attention_cost`) over the
-kernel's device time in the trace, in %.  Which bound applies goes to
-`ctx["notes"]`."""
-
-from counts import attention_cost
+peak and its bytes over the HBM peak, counted by the model module's
+`attention_cost`) over the kernel's device time in the trace, in %.  Which
+bound applies goes to `ctx["notes"]`."""
 
 # device op names of the Pallas paged-attention kernel in the trace
 KERNEL_NAMES = ("paged_flash_attention",)
@@ -18,7 +16,7 @@ def read(ctx):
                    if any(k in name for k in KERNEL_NAMES))
     if kernel_s <= 0:
         return None
-    cost = attention_cost(ctx["cfg"], sub["batches"])
+    cost = ctx["model"].attention_cost(ctx["cfg"], sub["batches"])
     peaks = ctx["peaks"]
     compute_s = cost["flops"] / peaks["bf16_flops_per_s"]
     memory_s = cost["bytes"] / peaks["hbm_bytes_per_s"]

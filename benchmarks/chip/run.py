@@ -4,9 +4,13 @@
         --seconds <s> --trace <0|1>
 
 A run builds the server of the cell's configuration through
-`build(ServeSpec(...))`, puts the seed's weights (made here) into it, serves
-one warm-up request, and counts that as set-up.  It then drives the cell's
-traffic in an open loop: a lead-in the traffic file sets, not counted, and a
+`build(ServeSpec(...))`, puts the seed's weights into it, serves one warm-up
+request, and counts that as set-up.  What depends on the architecture (the
+check that the program serves what the configuration file states, the
+weights, the plain reference and the operation counts) comes from the model
+module that the configuration names, `models/<model>.py`, found by that
+name (`manifest.model_module`).  It then drives the cell's traffic in an
+open loop: a lead-in the traffic file sets, not counted, and a
 window of `--seconds`, in which every request is timed from when it was due.
 With `--trace 1` a sub-window in the middle of the window is traced with
 the profiler and the per-layer metrics are read over it; otherwise the
@@ -14,9 +18,9 @@ end-to-end metrics are read over the window.
 
 Once the window has closed, the device's peak memory is read, the server is
 freed, and a sample of the requests the window finished is compared with
-the plain reference (`correct.py`).  Every number compared is printed beside
-its limit, as the last lines on standard error and under "checks" in the
-result, the last line on standard output.
+the model module's plain reference (`correct.py`).  Every number compared
+is printed beside its limit, as the last lines on standard error and under
+"checks" in the result, the last line on standard output.
 
 Exits non-zero, printing no result, when JAX finds no TPU or fewer chips
 than the cell asks for, and when the program is not in the checkout.
@@ -161,46 +165,23 @@ def log_window(loop, gcw, w0: float, w1: float) -> None:
         f"{max(v, default=0) * 1e3:.1f})" for g, v in by_gen.items()))
 
 
-def check_program_config(cfg, server) -> None:
-    """Raise unless the program serves the model the configuration file
-    states."""
-    pc, engine = server.cfg, server.replicas[0]
-    stacked = pc.layers_per_stage * pc.plan.pp
-    want = {"hidden_size": pc.d_model, "intermediate_size": pc.d_ff,
-            "num_hidden_layers": stacked if pc.num_layers == stacked
-            else f"{pc.num_layers} ({stacked} stacked)",
-            "num_attention_heads": pc.num_heads,
-            "num_key_value_heads": pc.num_kv_heads, "head_dim": pc.head_dim,
-            "vocab_size": pc.vocab_size, "rope_theta": pc.rope_theta,
-            "rms_norm_eps": pc.norm_eps, "qkv_bias": pc.qkv_bias,
-            "torch_dtype": pc.dtype, "hidden_act": pc.act,
-            "max_position_embeddings": engine.scheduler.max_model_len}
-    bad = {k: (cfg[k], v) for k, v in want.items() if cfg[k] != v}
-    if pc.norm != "rmsnorm" or pc.padded_vocab != pc.vocab_size:
-        bad["norm / padded vocab"] = ("rmsnorm / vocab",
-                                      f"{pc.norm} / {pc.padded_vocab}")
-    if bad:
-        raise ValueError(f"the program's {pc.name} differs from "
-                         f"{cfg['name']}'s file (file, program): {bad}")
-
-
-def build_server(cfg, seed: int):
-    """The cell's server, holding the seed's weights; returns it and the
-    weights (the benchmark's own arrays, kept for the reference)."""
+def build_server(cell, seed: int):
+    """The cell's server, holding the seed's weights made by the cell's
+    model module; returns it and the weights (the benchmark's own arrays,
+    kept for the reference)."""
     import jax
     from repro.serving import EngineSpec, ServeSpec, build
 
-    import weights
-
+    cfg = cell.config
     server = build(ServeSpec(backend="engine",
                              engine=EngineSpec(**cfg["engine"])))
-    check_program_config(cfg, server)
+    cell.model.check_program(cfg, server)
     engine = server.replicas[0]
     like = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=a.sharding), engine.params)
     # free the program's own random weights before making the seed's
     engine.params = engine.backend.params = None
-    params = weights.make_program_params(cfg, seed, like)
+    params = cell.model.make_program_params(cfg, seed, like)
     engine.params = engine.backend.params = params
     return server, params
 
@@ -320,7 +301,7 @@ def run_cell(cell, *, seed: int, seconds: float, trace: bool, devices,
     from traffic.generate import make_requests
 
     cfg, traffic = cell.config, cell.traffic
-    server, params = build_server(cfg, seed)
+    server, params = build_server(cell, seed)
     engine = server.replicas[0]
     warm_up(server, cfg["vocab_size"])
     warm_compiles = engine.backend.compile_count()
@@ -353,8 +334,9 @@ def run_cell(cell, *, seed: int, seconds: float, trace: bool, devices,
 
     records = loop.records
     in_window = [r for r in records if w0 <= r.due_s < w1]
-    ctx = {"cfg": cfg, "records": records, "window_records": in_window,
-           "window": (w0, w1), "setup_s": setup_s, "notes": {},
+    ctx = {"cfg": cfg, "model": cell.model, "records": records,
+           "window_records": in_window, "window": (w0, w1),
+           "setup_s": setup_s, "notes": {},
            "trace": None, "sub": None, "peaks": None}
     breakdown = None
     if sub is not None:
@@ -387,7 +369,7 @@ def run_cell(cell, *, seed: int, seconds: float, trace: bool, devices,
     del server, engine
     gc.collect()
     t_ref = time.perf_counter()
-    found = correct.compare(cfg, params, picked)
+    found = correct.compare(cell.model, cfg, params, picked)
     log(f"reference over {found['requests']} requests, {found['tokens']} "
         f"served tokens, in {time.perf_counter() - t_ref:.3f}s")
     checks = compared(cfg, found, correct.length_mismatches(picked), compiles)

@@ -13,7 +13,8 @@ rate is sustained when the waiting queue grows over the window by less than
 rate).  A chat request lives for minutes on a slow server, so the number in
 the system grows for that long even below the knee; the waiting queue grows
 only once the server cannot admit what arrives.  Requests still open at the
-window's end are aborted before the next rate.  Prints one JSON line per
+window's end are aborted before the next rate.  The sweep stops once
+`STOP_AFTER` rates in a row are not sustained.  Prints one JSON line per
 rate.  The benchmark's own runs never run this.
 """
 
@@ -30,6 +31,7 @@ import numpy as np  # noqa: E402
 import run  # noqa: E402  (puts this directory on the import path)
 
 GROWTH_LIMIT = 0.05
+STOP_AFTER = 2
 SERIES_S = 10
 
 
@@ -58,13 +60,14 @@ def main(argv=None) -> None:
     from traffic.generate import make_requests
 
     cfg = cell.config
-    server, _ = run.build_server(cfg, args.seed)
+    server, _ = run.build_server(cell, args.seed)
     run.warm_up(server, cfg["vocab_size"])
     run.log(f"set-up {time.perf_counter() - T_START:.3f}s")
     lead_in = (float(cell.traffic["lead_in_s"]) if args.lead_in is None
                else args.lead_in)
     sched = server.replicas[0].scheduler
     w0, w1 = lead_in, lead_in + args.seconds
+    unsustained = 0
     for k, rate in enumerate(float(r) for r in args.rates.split(",")):
         traffic = dict(cell.traffic, rate_per_s=rate, lead_in_s=lead_in)
         plan = make_requests(traffic, seed=args.seed, duration_s=w1,
@@ -84,6 +87,8 @@ def main(argv=None) -> None:
         gaps = itls_s(loop.records, w0, w1)
         samples_all, samples = samples, [x for x in samples if x[0] >= w0]
         growth = backlog_growth([(t, w) for t, w, _ in samples])
+        sustained = growth < GROWTH_LIMIT * rate
+        unsustained = 0 if sustained else unsustained + 1
         print(json.dumps({
             "rate_per_s": rate, "requests": len(win),
             "waiting_start": samples[0][1], "waiting_end": samples[-1][1],
@@ -91,7 +96,7 @@ def main(argv=None) -> None:
             "in_system_start": samples[0][2], "in_system_end": samples[-1][2],
             "in_system_growth_per_s": backlog_growth(
                 [(t, n) for t, _, n in samples]),
-            "sustained": growth < GROWTH_LIMIT * rate,
+            "sustained": sustained,
             "preemptions": sum(r.preempted for r in loop.records),
             "ttft_p50_ms": percentile(ttft, 50) * 1e3,
             "ttft_p90_ms": percentile(ttft, 90) * 1e3,
@@ -105,6 +110,8 @@ def main(argv=None) -> None:
                         / SERIES_S] for t, w, n in samples_all
                        if t % SERIES_S == 0]}), flush=True)
         loop.abort_open()
+        if unsustained == STOP_AFTER:
+            break
 
 
 if __name__ == "__main__":

@@ -1,6 +1,8 @@
 """Shared set-up of the benchmark's CPU tests: the benchmark's directory on
-the import path, and a tiny cell that runs the harness end to end on the
-CPU through the program's chip-mode build."""
+the import path, and tiny cells that run the harness end to end on the CPU
+through the program's chip-mode build: `tiny` (8 heads of 64 over 4 KV
+heads, QKV bias) and `tiny-gqa128` (InternLM2's shape: 4 heads of 128 over
+2 KV heads, no bias, untied head)."""
 
 import sys
 from pathlib import Path
@@ -19,6 +21,7 @@ TINY_DIMS = {"pages": 256, "page": 8, "C": 32, "Sp": 1, "Sd": 8, "Bp": 32,
 
 TINY_CONFIG = {
     "name": "tiny",
+    "model": "dense_decoder",
     "engine": {"arch": "tiny", "reduced": False, "stages": 1,
                "policy": "gllm", "dispatch": "async", "bucketed": False,
                "enable_prefix_caching": False, "dims": TINY_DIMS},
@@ -36,6 +39,17 @@ TINY_CONFIG = {
     "check": {"logit_gap_limit": 0.015, "logprob_error_limit": 0.018},
 }
 
+TINY_GQA128_CONFIG = dict(
+    TINY_CONFIG, name="tiny-gqa128",
+    engine=dict(TINY_CONFIG["engine"], arch="tiny-gqa128"),
+    num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+    qkv_bias=False, tie_word_embeddings=False)
+# tiny's limits hold here too: on the CPU, over six seeds, bf16 serving read
+# logit gaps of 0 to 4.2e-3 and log-prob errors of 6.4e-3 to 9.4e-3, the fp8
+# control 6.6e-2 to 0.11 and 0.10 to 0.16 on the same requests
+
+TINY_CONFIGS = {c["name"]: c for c in (TINY_CONFIG, TINY_GQA128_CONFIG)}
+
 TINY_TRAFFIC = {
     "name": "tiny-chat", "generator": "lognormal_poisson",
     "rate_per_s": 20.0, "lead_in_s": 0.5,
@@ -45,26 +59,34 @@ TINY_TRAFFIC = {
 }
 
 
-def tiny_arch():
+def tiny_arch(cfg=TINY_CONFIG):
+    """The program's architecture of the tiny configuration `cfg`."""
     from repro.configs import ArchConfig, BlockKind, BlockSpec, ParallelPlan
+    L = cfg["num_hidden_layers"]
     return ArchConfig(
-        name="tiny", family="dense", num_layers=2, d_model=512, num_heads=8,
-        num_kv_heads=4, head_dim=64, d_ff=1024, vocab_size=4096,
-        pattern=(BlockSpec(BlockKind.ATTN_MLP, 2),),
-        plan=ParallelPlan(pp=1, tp=1), qkv_bias=True, rope_theta=1e4)
+        name=cfg["name"], family="dense", num_layers=L,
+        d_model=cfg["hidden_size"], num_heads=cfg["num_attention_heads"],
+        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
+        pattern=(BlockSpec(BlockKind.ATTN_MLP, L),),
+        plan=ParallelPlan(pp=1, tp=1), qkv_bias=cfg["qkv_bias"],
+        rope_theta=cfg["rope_theta"])
 
 
 @pytest.fixture
-def tiny_cell(monkeypatch):
-    """A one-chip cell of the tiny model, with the program's registry
-    answering the tiny architecture; the end-to-end metrics of the chat
-    cells."""
+def tiny_cell(request, monkeypatch):
+    """A one-chip cell of a tiny model (`tiny`, or the name given as the
+    fixture's indirect parameter), with the program's registry answering
+    its architecture; the end-to-end metrics of the chat cells."""
     import repro.configs
 
     import manifest
-    monkeypatch.setattr(repro.configs, "get_config", lambda name: tiny_arch())
+    cfg = TINY_CONFIGS[getattr(request, "param", "tiny")]
+    monkeypatch.setattr(repro.configs, "get_config",
+                        lambda name: tiny_arch(cfg))
     e2e = [manifest.Metric(n, u) for n, u in (
         ("ttft_p50_ms", "ms"), ("ttft_p90_ms", "ms"), ("itl_p95_ms", "ms"),
         ("output_tokens_per_s", "tokens/s"), ("setup_s", "s"))]
-    return manifest.Cell("tiny.chat", 1, dict(TINY_CONFIG),
-                         dict(TINY_TRAFFIC), e2e, [])
+    return manifest.Cell(f"{cfg['name']}.chat", 1, dict(cfg),
+                         dict(TINY_TRAFFIC), e2e, [],
+                         manifest.model_module(cfg["model"]))

@@ -2,11 +2,15 @@
 lower precision (fp8) must come out not correct under the limits that the
 program's own served tokens meet, on the same requests."""
 
+import pytest
+
 import correct
 import run
+from conftest import TINY_CONFIGS
 from serve_loop import Record
 
 
+@pytest.mark.parametrize("tiny_cell", sorted(TINY_CONFIGS), indirect=True)
 def test_fp8_control_fails_where_the_program_passes(tiny_cell):
     from repro.serving import SamplingParams
     from traffic.generate import make_requests
@@ -14,7 +18,7 @@ def test_fp8_control_fails_where_the_program_passes(tiny_cell):
     cfg, seed = tiny_cell.config, 2 ** 31 + 1
     limits = {k: cfg["check"][f"{k}_limit"]
               for k in ("logit_gap", "logprob_error")}
-    server, params = run.build_server(cfg, seed)
+    server, params = run.build_server(tiny_cell, seed)
     # every request of the schedule, served to the end: the same work
     # however busy the CPU is
     records = [Record(p, f"c-{p.index}") for p in make_requests(
@@ -30,8 +34,9 @@ def test_fp8_control_fails_where_the_program_passes(tiny_cell):
         r.finish_reason = out.finish_reason
     picked = correct.sample(records, seed)
     run.free_server(server)
-    program = correct.compare(cfg, params, picked)
-    control = correct.compare(cfg, params, picked, control="fp8")
+    model = tiny_cell.model
+    program = correct.compare(model, cfg, params, picked)
+    control = correct.compare(model, cfg, params, picked, control="fp8")
     assert correct.length_mismatches(picked) == 0
     assert program["tokens"] == control["tokens"] >= 64
     assert all(program[k] <= v for k, v in limits.items()), program

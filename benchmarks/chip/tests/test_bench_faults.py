@@ -11,6 +11,11 @@ import jax.numpy as jnp
 import pytest
 
 import run
+from conftest import TINY_CONFIGS
+
+# every test here runs over each tiny configuration
+pytestmark = pytest.mark.parametrize("tiny_cell", sorted(TINY_CONFIGS),
+                                     indirect=True)
 
 
 @pytest.fixture(autouse=True)
@@ -28,8 +33,8 @@ def _break_ticks(monkeypatch, wrap):
     `wrap(tick)`."""
     build = run.build_server
 
-    def broken_build(cfg, seed):
-        server, params = build(cfg, seed)
+    def broken_build(cell, seed):
+        server, params = build(cell, seed)
         backend = server.replicas[0].backend
         get = backend._get_tick
         backend._get_tick = lambda bucket: wrap(get(bucket))
